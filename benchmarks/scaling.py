@@ -55,8 +55,11 @@ print("RESULT " + json.dumps({{"mean_s": float(np.mean(ts)),
 def _run_worker(ndev, n_per_rank, backend="jnp", repeats=3):
     cfg = json.dumps({"n_per_rank": n_per_rank, "ndev": ndev,
                       "backend": backend, "repeats": repeats})
+    # the worker simulates an ndev mesh on fake host devices: pinned to the
+    # CPU on purpose (this process may already hold the accelerator)
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")
     code = textwrap.dedent(_WORKER).format(cfg=cfg)

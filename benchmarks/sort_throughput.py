@@ -59,6 +59,17 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_JSON = os.path.join(REPO, "BENCH_sort.json")
 
 
+def _host_mesh_env(nranks: int) -> dict:
+    """Environment for a child that simulates an ``nranks`` mesh on fake
+    host devices. Pinned to the CPU on purpose: this process may already
+    hold the accelerator, and the child's counts need no chip."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nranks}"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    return env
+
+
 def _count_launches(n: int, dtype, hyper: int) -> int:
     """Trace-time launch count of one n-element sort at hyper order m."""
     x = jax.ShapeDtypeStruct((n,), dtype)
@@ -264,13 +275,11 @@ def run_distributed(n: int = 2**20, nranks: int = 8,
     Modelled HBM + interconnect bytes/times come from
     ``benchmarks/cost.py::sihsort_cost`` and land in ``BENCH_sort.json``.
     """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nranks}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, "-c", _DISTRIBUTED_CHILD,
          str(n), str(nranks), str(capacity_factor)],
-        env=env, capture_output=True, text=True, timeout=600,
+        env=_host_mesh_env(nranks), capture_output=True, text=True,
+        timeout=600,
     )
     if proc.returncode != 0:
         raise RuntimeError(
@@ -420,9 +429,7 @@ def run_hetero(n: int = 2**16, n_model: int = 2**20,
         lower than the uniform cut.
     """
     nranks = len(backends)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nranks}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env = _host_mesh_env(nranks)
     proc = subprocess.run(
         [sys.executable, "-c", _HETERO_CHILD, ",".join(backends),
          str(n), str(n_model), str(capacity_factor)],

@@ -219,8 +219,9 @@ if _args.paged:
 
 # -- optional: heterogeneous co-sort (DESIGN.md §12) ------------------------
 # Mixed-backend co-processing needs a multi-rank mesh, so this vignette
-# hands off to the distributed demo, which self-relaunches with 8 fake
-# host devices and runs two jnp ranks beside six Pallas ranks.
+# hands off to the distributed demo in a child process pinned to the CPU on
+# purpose: it simulates an 8-rank mesh on host devices (two jnp ranks
+# beside six Pallas ranks), and this process may already hold the chip.
 if _args.co_sort:
     import subprocess
     import sys
@@ -228,6 +229,7 @@ if _args.co_sort:
     demo = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "distributed_sort.py")
     print("\nco-sort vignette  : examples/distributed_sort.py --hetero")
-    rc = subprocess.call([sys.executable, demo, "--hetero"])
+    rc = subprocess.call([sys.executable, demo, "--hetero"],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
     if rc != 0:
         raise SystemExit(rc)

@@ -1,62 +1,37 @@
-"""Version compatibility shims for the distributed layer.
+"""The distributed layer's spellings of JAX's mesh/shard_map surface.
 
-The mesh/shard_map surface moved between jax releases: ``jax.shard_map``
-(with ``check_vma``) and ``jax.lax.axis_size`` are the current spellings,
-older releases (≤ 0.4.x) spell them ``jax.experimental.shard_map.shard_map``
-(with ``check_rep``) and have no axis-size helper at all, and
-``jax.sharding.AxisType`` does not exist yet. Everything that crosses that
-surface goes through this module so the distributed sort (and its tests)
-run on both — the container pins an older jax than the code was written
-against, and a TPU pod will pin a newer one.
+Written for the installed JAX (0.9): ``jax.shard_map`` with ``check_vma``,
+``jax.lax.axis_size``, ``jax.extend.core`` and explicit mesh axis types.
+Everything that crosses that surface goes through these few names, so a
+future API move is one edit here instead of one per call site.
 """
 from __future__ import annotations
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` when available, else the experimental spelling
-    (whose replication check is called ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 
 def axis_size(axis_name) -> int:
     """Static size of a named mesh axis from inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    from jax._src import core as _core
-
-    return _core.get_axis_env().axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def jaxpr_types() -> tuple:
-    """(Jaxpr, ClosedJaxpr) classes across the jax.core → jax.extend.core
-    move: newer releases delete them from ``jax.core``, older ones don't
-    have ``jax.extend.core`` yet. Used by the collective counter's jaxpr
-    walk (``core.distributed.count_collectives``)."""
-    try:
-        from jax.extend.core import ClosedJaxpr, Jaxpr
-    except ImportError:
-        from jax.core import ClosedJaxpr, Jaxpr
+    """(Jaxpr, ClosedJaxpr) classes, for the collective counter's jaxpr walk
+    (``core.distributed.count_collectives``)."""
     return Jaxpr, ClosedJaxpr
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where the release
-    supports them (newer jax defaults every axis to Auto anyway)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` over ``jax.devices()``, every axis Auto-sharded."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )

@@ -139,7 +139,7 @@ def nucleus_mask(x, *, top_p: float, backend: str | None = None):
     (descending ``sortperm_batched`` + vmapped ``accumulate`` + vmapped
     ``searchsortedfirst`` + scatter): the portable path is the XLA oracle,
     the Pallas path re-enters the batched bitonic network and finishes with
-    a single fused softmax/prefix-sum/cut/scatter launch
+    a single fused softmax/prefix-sum/cut launch
     (kernels/nucleus_kernel.py). ``top_p`` is static (host float).
     """
     return _nucleus_mask(x, top_p=float(top_p), backend=backend)

@@ -2,9 +2,11 @@
 
 All kernels in this package are written against the TPU lowering rules
 (2-D blocks, last dim a multiple of 128, second-to-last a multiple of the
-sublane count) and are validated on CPU with ``interpret=True`` — the kernel
-body runs in Python with jnp semantics, which is the container-supported
-path (this box has no TPU).
+sublane count) and compile through Mosaic for TPU chips
+(tests/test_tpu_compile.py compiles each for a described v5e). Off the
+TPU they run with ``interpret=True``: the kernel body executes as ordinary
+XLA ops on the CPU, which is how the test suite checks them against the
+jnp oracles.
 """
 from __future__ import annotations
 
@@ -16,6 +18,7 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.runtime import telemetry
 
@@ -172,6 +175,22 @@ def pallas_call(*args, **kwargs):
         _launch_by_label[label] = _launch_by_label.get(label, 0) + 1
     telemetry.attribute(launches=1)
     return pl.pallas_call(*args, **kwargs)
+
+
+def xor_partner(x: jax.Array, d: int, axis: int) -> jax.Array:
+    """``out[i] = x[i ^ d]`` along ``axis`` (``d`` a power of two below the
+    axis length): the compare-exchange partner of every slot. On the chip,
+    two rotations and a select on the index bit; interpreted, a reversal
+    of each (low, high) pair, which compiles to a fraction of the host
+    code. Both only move data, so they give the same bits."""
+    n = x.shape[axis]
+    if interpret_mode():
+        pairs = x.shape[:axis] + (n // (2 * d), 2, d) + x.shape[axis + 1:]
+        return jnp.flip(x.reshape(pairs), axis + 1).reshape(x.shape)
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    up = pltpu.roll(x, n - d, axis)    # up[i] = x[i + d]
+    down = pltpu.roll(x, d, axis)      # down[i] = x[i - d]
+    return jnp.where((idx & d) == 0, up, down)
 
 
 def ceil_div(a: int, b: int) -> int:

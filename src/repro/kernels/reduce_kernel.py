@@ -10,9 +10,12 @@ there is only ever ONE launch here.
 
 The accumulator is (8, 128) vector-shaped rather than scalar: reducing each
 (8, 1024) block to a scalar every grid step would serialise on the scalar
-unit; folding to a vreg keeps the VPU busy, and the vreg is collapsed to a
-scalar once, in the final grid step.  This mirrors the paper's "no warp
-shuffles, still fast" design point — partials stay in vector registers.
+unit; folding to a vreg keeps the VPU busy, and the vreg is collapsed once,
+in the final grid step, by a halving tree of sublane and lane rotations
+that leaves the total in every element. The kernel's output is that
+(8, 128) block; the caller reads element [0, 0]. This mirrors the paper's
+"no warp shuffles, still fast" design point — partials stay in vector
+registers, and no scalar is ever stored to vector memory.
 """
 from __future__ import annotations
 
@@ -28,15 +31,36 @@ from repro.kernels import common as C
 _ACC_ROWS, _ACC_COLS = C.SUBLANES, C.LANES
 
 
+def _collapse(op, a):
+    """Fold an (R, L) block (powers of two) to its op-total in element
+    [0, 0]: halving trees over sublanes, then lanes. Rotating by
+    ``size - half`` brings element ``i + half`` to ``i``, so element 0
+    combines (lower half, upper half) exactly as a halving tree of slices."""
+    for axis in (0, 1):
+        half = a.shape[axis] // 2
+        while half >= 1:
+            a = op(a, pltpu.roll(a, a.shape[axis] - half, axis))
+            half //= 2
+    return a
+
+
 def _reduce_body(f, op, unit, n_ops, *refs):
     # refs = (*in_refs, out_ref, acc_ref)
     i = pl.program_id(0)
     acc, out = refs[-1], refs[-2]
     ins = [refs[k][...] for k in range(n_ops)]
     mapped = f(*ins)  # (BLOCK_ROWS, BLOCK_COLS)
-    # Fold the (8, 1024) block into an (8, 128) vreg-shaped partial.
-    part = mapped.reshape(_ACC_ROWS, -1, _ACC_COLS)
-    part = functools.reduce(op, [part[:, j, :] for j in range(part.shape[1])])
+    # Fold the (8, 1024) block into an (8, 128) vreg-shaped partial:
+    # lane-aligned column slices, combined left to right.
+    rows, cols = mapped.shape
+    part = mapped[:, :_ACC_COLS]
+    for j in range(1, cols // _ACC_COLS):
+        part = op(part, mapped[:, j * _ACC_COLS:(j + 1) * _ACC_COLS])
+    if rows != _ACC_ROWS:
+        folded = part[:_ACC_ROWS]
+        for r in range(1, rows // _ACC_ROWS):
+            folded = op(folded, part[r * _ACC_ROWS:(r + 1) * _ACC_ROWS])
+        part = folded
 
     @pl.when(i == 0)
     def _init():
@@ -46,14 +70,7 @@ def _reduce_body(f, op, unit, n_ops, *refs):
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _fin():
-        a = acc[...]
-        r = functools.reduce(op, [a[k, :] for k in range(_ACC_ROWS)])
-        # Collapse 128 lanes with a log2 tree of vector halves.
-        length = _ACC_COLS
-        while length > 1:
-            length //= 2
-            r = op(r[:length], r[length:])
-        out[0, 0] = r[0]
+        out[...] = _collapse(op, acc[...])
 
 
 def reduce_blocks(f, op, *arrays: jax.Array, unit, out_dtype=None) -> jax.Array:
@@ -74,8 +91,8 @@ def reduce_blocks(f, op, *arrays: jax.Array, unit, out_dtype=None) -> jax.Array:
         functools.partial(_reduce_body, f, op, unit, len(views)),
         grid=grid,
         in_specs=[spec] * len(views),
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), out_dtype),
+        out_specs=pl.BlockSpec((_ACC_ROWS, _ACC_COLS), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((_ACC_ROWS, _ACC_COLS), out_dtype),
         scratch_shapes=[pltpu.VMEM((_ACC_ROWS, _ACC_COLS), out_dtype)],
         interpret=C.interpret_mode(),
     )(*views)

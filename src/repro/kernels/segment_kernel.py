@@ -21,8 +21,8 @@ segmented scan: carry ``(flag, value)`` pairs under the associative combine
 
 which resets accumulation at every segment head.  That drops straight into
 ``scan_kernel``'s sequential-grid machinery — the Hillis–Steele lane tree,
-the per-row carry fold, and the (1, 1) VMEM carry scratch all stay, each
-now carrying a flag beside the value.  Segment boundaries cost one extra
+the per-row carry fold, and the (1, L) vector carry scratch all stay, each
+now carrying an int32 flag beside the value.  Segment boundaries cost one extra
 int32 flag stream; there is no per-segment launch, so the launch count is
 identical to the dense scan: ``rows / block_rows`` for one pass.
 
@@ -90,15 +90,16 @@ def _flagged_row_scan(op, v, f):
 
     Hillis–Steele with the flagged combine: a lane stops absorbing its
     left neighbourhood once its accumulated window contains a head flag.
+    ``f`` is int32 0/1 (flags are rotated and selected as integers).
     """
     r, l = v.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, l), 1)
     shift = 1
     while shift < l:
-        pv = jnp.pad(v, ((0, 0), (shift, 0)))[:, :l]
-        pf = jnp.pad(f, ((0, 0), (shift, 0)))[:, :l]
-        lane = jax.lax.broadcasted_iota(jnp.int32, (r, l), 1)
+        pv = pltpu.roll(v, shift, 1)
+        pf = pltpu.roll(f, shift, 1)
         has = lane >= shift
-        v = jnp.where(has & ~f, op(pv, v), v)
+        v = jnp.where(has & (f == 0), op(pv, v), v)
         f = jnp.where(has, f | pf, f)
         shift *= 2
     return v, f
@@ -108,22 +109,25 @@ def _segscan_block(op, carry, v, f):
     """One (R, L) block of the segmented scan given an inter-block carry.
 
     ``carry = (cv, cf)`` is the accumulated (value, seen-a-flag) pair for
-    everything before this block. Returns the block output and new carry.
+    everything before this block, each a (1, L) row with every lane equal.
+    Returns the block output and new carry.
     """
     cv, cf = carry
     v, f = _flagged_row_scan(op, v, f)
-    totals_v, totals_f = v[:, -1], f[:, -1]
-    row_cv, row_cf = [], []
-    for r in range(v.shape[0]):
-        row_cv.append(cv)
-        row_cf.append(cf)
-        cf, cv = _flag_combine(op, cf, cv, totals_f[r], totals_v[r])
-    row_cv = jnp.stack(row_cv)[:, None]  # (R, 1)
-    row_cf = jnp.stack(row_cf)[:, None]
-    del row_cf  # the carry flag never changes an element's value
+    shape = v.shape
+    totals_v = jnp.broadcast_to(v[:, -1:], shape)
+    totals_f = jnp.broadcast_to(f[:, -1:], shape)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    row_cv = jnp.broadcast_to(cv, shape)
+    for r in range(shape[0]):
+        row_cv = jnp.where(row == r, jnp.broadcast_to(cv, shape), row_cv)
+        tf = totals_f[r:r + 1]
+        cv = jnp.where(tf != 0, totals_v[r:r + 1], op(cv, totals_v[r:r + 1]))
+        cf = cf | tf
     # Element i absorbs the row carry only if no head flag precedes it
-    # within the row (its accumulated flag is clear).
-    out = jnp.where(f, v, op(row_cv, v))
+    # within the row (its accumulated flag is clear); the carry flag never
+    # changes an element's value.
+    out = jnp.where(f != 0, v, op(row_cv, v))
     return out, (cv, cf)
 
 
@@ -135,13 +139,12 @@ def _segscan_body(op, unit, v_ref, f_ref, o_ref, cv_ref, cf_ref):
         cv_ref[...] = jnp.full(cv_ref.shape, unit, cv_ref.dtype)
         cf_ref[...] = jnp.zeros(cf_ref.shape, cf_ref.dtype)
 
-    v = v_ref[...]
-    f = f_ref[...] != 0
-    carry = (cv_ref[0, 0], cf_ref[0, 0] != 0)
-    out, (cv, cf) = _segscan_block(op, carry, v, f)
+    out, (cv, cf) = _segscan_block(
+        op, (cv_ref[...], cf_ref[...]), v_ref[...], f_ref[...]
+    )
     o_ref[...] = out
-    cv_ref[0, 0] = cv
-    cf_ref[0, 0] = cf.astype(cf_ref.dtype)
+    cv_ref[...] = cv
+    cf_ref[...] = cf
 
 
 def _exclusive_shift(inclusive, flags, unit):
@@ -171,8 +174,8 @@ def segmented_scan_blocks(op, values, offsets, *, unit,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(view_v.shape, values.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), values.dtype),
-            pltpu.VMEM((1, 1), jnp.int32),
+            pltpu.VMEM((1, bc), values.dtype),
+            pltpu.VMEM((1, bc), jnp.int32),
         ],
         interpret=C.interpret_mode(),
     )(view_v, view_f)

@@ -5,7 +5,7 @@ radix sort "requires intrinsics for high performance" (paper §I-B).  The TPU
 portable layer has the same constraint *plus* a vector memory that hates the
 data-dependent branches of a sequential merge path.  The TPU-idiomatic
 equivalent is a **bitonic sorting network**: every compare-exchange step is a
-branch-free reshape + min/max + select over whole (8·k, 1024) vector
+branch-free rotate + min/max + select over whole (8·k, 1024) vector
 registers, with zero gathers — trading the O(n log n) of merge sort for
 O(n log² n) *perfectly vectorised* work.  (DESIGN.md §2 records this as a
 hardware adaptation; the AK "merge" view survives inside the network — a
@@ -99,13 +99,11 @@ def _hyper_order() -> int:
     return HYPER_ORDER if m is None else m
 
 
-def _flat_iota(shape, mults):
-    """Global flat index tensor: sum_i iota_axis_i * mults[i]."""
-    acc = None
-    for ax, m in enumerate(mults):
-        io = jax.lax.broadcasted_iota(jnp.int32, shape, ax) * m
-        acc = io if acc is None else acc + io
-    return acc
+def _bit(x, p):
+    """Bit ``p`` (a power of two) of int32 ``x`` as 0/1 int32. The network's
+    masks stay int32 until the final compare: Mosaic cannot select between
+    boolean vectors, so no boolean is ever a select operand."""
+    return (x >> (p.bit_length() - 1)) & 1
 
 
 def _cx(keys, vals, j, k, base, tie_break):
@@ -113,82 +111,57 @@ def _cx(keys, vals, j, k, base, tie_break):
     (R, L) block whose first element has global flat index ``base``.
 
     Returns the exchanged (keys, vals). ``vals`` may be None (key-only).
-    ``asc`` per pair = ((global index of the low element) & k) == 0.
+    Every slot fetches its partner ``i ^ j`` (``common.xor_partner``: along
+    lanes for ``j < L``, along sublanes — ``j // L`` rows — otherwise), so
+    no reshape touches the vector layout. Both slots of a pair evaluate the
+    same (low, high) comparison, so they agree on the outcome; the pair
+    sorts ascending iff bit ``k`` of its index is clear.
     """
     R, L = keys.shape
-
-    def pairs(x, f):
-        if j < L:
-            y = x.reshape(R, L // (2 * j), 2, j)
-            a, b = y[:, :, 0, :], y[:, :, 1, :]
-            na, nb = f(a, b)
-            return jnp.stack([na, nb], axis=2).reshape(R, L)
-        m = j // L
-        y = x.reshape(R // (2 * m), 2, m, L)
-        a, b = y[:, 0], y[:, 1]
-        na, nb = f(a, b)
-        return jnp.stack([na, nb], axis=1).reshape(R, L)
-
-    # Flat global index of each "a" (low) slot.
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, L), 1)
     if j < L:
-        ashape = (R, L // (2 * j), j)
-        flat_a = _flat_iota(ashape, (L, 2 * j, 1)) + base
+        axis, d, high = 1, j, _bit(col, j)
     else:
-        m = j // L
-        ashape = (R // (2 * m), m, L)
-        flat_a = _flat_iota(ashape, (2 * m * L, L, 1)) + base
-    asc = (flat_a & k) == 0
+        axis, d, high = 0, j // L, _bit(row, j // L)
+    desc = _bit(row * L + col + base, k)
+    low = high == 0
 
+    def partner(x):
+        return C.xor_partner(x, d, axis)
+
+    pk = partner(keys)
+    lo_k, hi_k = jnp.where(low, keys, pk), jnp.where(low, pk, keys)
     if vals is None:
-        def f(a, b):
-            lo, hi = jnp.minimum(a, b), jnp.maximum(a, b)
-            return jnp.where(asc, lo, hi), jnp.where(asc, hi, lo)
-
-        return pairs(keys, f), None
+        mn, mx = jnp.minimum(lo_k, hi_k), jnp.maximum(lo_k, hi_k)
+        return jnp.where((high ^ desc) == 0, mn, mx), None
 
     # Key-value: one swap predicate drives both planes, with optional
     # (key, value)-lexicographic tie-break (used by sortperm so ties resolve
     # to ascending index == stable argsort order).
-    def pairs_kv(xk, xv):
-        if j < L:
-            yk = xk.reshape(R, L // (2 * j), 2, j)
-            yv = xv.reshape(R, L // (2 * j), 2, j)
-            ak, bk = yk[:, :, 0, :], yk[:, :, 1, :]
-            av, bv = yv[:, :, 0, :], yv[:, :, 1, :]
-            stack_ax = 2
-        else:
-            m = j // L
-            yk = xk.reshape(R // (2 * m), 2, m, L)
-            yv = xv.reshape(R // (2 * m), 2, m, L)
-            ak, bk = yk[:, 0], yk[:, 1]
-            av, bv = yv[:, 0], yv[:, 1]
-            stack_ax = 1
-        gt = ak > bk
-        if tie_break:
-            gt = gt | ((ak == bk) & (av > bv))
-        swap = jnp.where(asc, gt, ~gt)
-        nak = jnp.where(swap, bk, ak)
-        nbk = jnp.where(swap, ak, bk)
-        nav = jnp.where(swap, bv, av)
-        nbv = jnp.where(swap, av, bv)
-        ok = jnp.stack([nak, nbk], axis=stack_ax).reshape(R, L)
-        ov = jnp.stack([nav, nbv], axis=stack_ax).reshape(R, L)
-        return ok, ov
-
-    return pairs_kv(keys, vals)
+    pv = partner(vals)
+    lo_v, hi_v = jnp.where(low, vals, pv), jnp.where(low, pv, vals)
+    gt = lo_k > hi_k
+    if tie_break:
+        gt = gt | ((lo_k == hi_k) & (lo_v > hi_v))
+    swap = (gt.astype(jnp.int32) ^ desc) != 0
+    return jnp.where(swap, pk, keys), jnp.where(swap, pv, vals)
 
 
-def _swap_blocks(ka, kb, va, vb, asc, tie_break):
+def _swap_blocks(ka, kb, va, vb, desc, tie_break):
     """Whole-block compare-exchange: every lane of block ``a`` against the
-    same lane of block ``b``, direction ``asc`` (scalar — uniform across the
-    pair because all member-varying index bits sit strictly below k)."""
+    same lane of block ``b``. ``desc`` is an int32 scalar (1 = descending),
+    uniform across the pair because all member-varying index bits sit
+    strictly below k."""
+    flip = jnp.full(ka.shape, desc, jnp.int32)
     if va is None:
         lo, hi = jnp.minimum(ka, kb), jnp.maximum(ka, kb)
-        return (jnp.where(asc, lo, hi), jnp.where(asc, hi, lo), None, None)
+        up = flip == 0
+        return (jnp.where(up, lo, hi), jnp.where(up, hi, lo), None, None)
     gt = ka > kb
     if tie_break:
         gt = gt | ((ka == kb) & (va > vb))
-    swap = jnp.where(asc, gt, ~gt)
+    swap = (gt.astype(jnp.int32) ^ flip) != 0
     return (
         jnp.where(swap, kb, ka),
         jnp.where(swap, ka, kb),
@@ -227,7 +200,7 @@ def _hyper_body(k, H, S, tail, tie_break, has_vals, block, *refs):
     """
     q, r = pl.program_id(0), pl.program_id(1)
     base_block = q * (H * S) + r
-    asc = ((base_block * block) & k) == 0
+    desc = _bit(base_block * block, k)
     if has_vals:
         k_ref, v_ref, ok_ref, ov_ref = refs
         vals = [v_ref[0, t, 0] for t in range(H)]
@@ -246,7 +219,7 @@ def _hyper_body(k, H, S, tail, tie_break, has_vals, block, *refs):
                 keys[t], keys[u],
                 None if vals is None else vals[t],
                 None if vals is None else vals[u],
-                asc, tie_break,
+                desc, tie_break,
             )
             keys[t], keys[u] = ka, kb
             if vals is not None:
@@ -262,9 +235,10 @@ def _hyper_body(k, H, S, tail, tie_break, has_vals, block, *refs):
             if vals is not None:
                 vals[t] = nv
 
-    ok_ref[0, :, 0] = jnp.stack(keys)
-    if has_vals:
-        ov_ref[0, :, 0] = jnp.stack(vals)
+    for t in range(H):
+        ok_ref[0, t, 0] = keys[t]
+        if has_vals:
+            ov_ref[0, t, 0] = vals[t]
 
 
 def _stages_upto_block(k, block):

@@ -6,7 +6,7 @@ The sampler is deliberately built from the paper's primitives — this is the
     top-k cut       -> ak.topk                     (sort-derived)
     top-p (nucleus) -> ak.nucleus_mask             (ONE fused registry call:
                        descending sortperm + inclusive prefix sum + top-p
-                       cut + keep-mask scatter; kernels/nucleus_kernel.py)
+                       cut + keep comparison; kernels/nucleus_kernel.py)
 
 ``fused=False`` keeps the historical unfused composition (sortperm_batched
 + vmapped accumulate + vmapped searchsortedfirst + XLA scatter) — the
@@ -262,10 +262,20 @@ def _serve_loop_fixed(params, cfg, prompts, *, max_new, cache_len,
 
 
 def main(argv=None):
-    from repro.configs import load_smoke_config
+    """Serve seeded random prompts with random weights; returns
+    ``(results, EngineStats)`` for engine families (``None`` for the
+    encdec/vlm fallback)."""
+    from repro.configs import load_config, load_smoke_config
+    from repro.runtime import compile_cache
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2_1_8b")
+    ap.add_argument("--full", action="store_true",
+                    help="build the architecture's published CONFIG "
+                         "(full widths and depth) instead of the 64-wide "
+                         "smoke preset")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -328,9 +338,10 @@ def main(argv=None):
             metrics.write(args.metrics)
             print(f"metrics: snapshot -> {args.metrics}")
 
-    cfg = load_smoke_config(args.arch)
-    rng = jax.random.PRNGKey(0)
-    params = M.init_params(rng, cfg)
+    compile_cache.enable()
+    cfg = (load_config if args.full else load_smoke_config)(args.arch)
+    rng = jax.random.PRNGKey(args.seed)
+    params = jax.jit(M.init_params, static_argnums=1)(rng, cfg)
     prompts = np.asarray(jax.random.randint(
         rng, (args.requests, args.prompt_len), 0, cfg.vocab
     ))
@@ -353,7 +364,7 @@ def main(argv=None):
         eng = Engine(
             params, cfg, slots=args.slots, cache_len=cache_len,
             prompt_pad=args.prompt_len, top_k=args.top_k, top_p=args.top_p,
-            eos_id=args.eos, fused_sampler=not args.unfused,
+            seed=args.seed, eos_id=args.eos, fused_sampler=not args.unfused,
             paged=args.paged, page_size=args.page_size,
             num_pages=args.num_pages, defrag_every=args.defrag_every,
             preempt=args.preempt or chaos, queue_cap=args.queue_cap,
@@ -369,6 +380,10 @@ def main(argv=None):
                 for i in range(args.requests)
             ])
         done = sum(r.finished_step >= 0 for r in results.values())
+        print(
+            f"{cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+            f"{M.param_count(params):,} params"
+        )
         print(
             f"served {done}/{args.requests} requests on {args.slots} slots; "
             f"{stats.tokens} tokens in {stats.steps} steps; "
@@ -406,7 +421,7 @@ def main(argv=None):
                 f"rejections={stats.rejections} timeouts={stats.timeouts}"
             )
         export_obs()
-        return
+        return results, stats
 
     # encdec/vlm: fixed-batch fallback
     extras = {}

@@ -18,9 +18,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_multidevice(code: str, ndev: int = 8, timeout: int = 600):
-    """Run ``code`` in a subprocess with ``ndev`` fake host devices."""
+    """Run ``code`` in a subprocess with ``ndev`` fake host devices, pinned
+    to the CPU on purpose (the mesh is simulated on the host)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

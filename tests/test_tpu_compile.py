@@ -1,0 +1,136 @@
+"""Ahead-of-time compiles for a TPU v5e chip, without the chip.
+
+The TPU compiler is installed next to JAX, so a program can be compiled for
+a *described* ``v5e:2x2`` topology: what Mosaic or XLA would refuse on the
+chip (layouts interpret mode accepts, scalars stored to vector memory,
+primitives without a TPU lowering, programs that do not fit HBM) fails
+here, at no chip time. Nothing runs. The topology is described inside a
+fixture — never at import — so every pytest worker collects the same tests
+and only the worker that runs this file loads the TPU library.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import common as C
+from repro.kernels import hist_kernel, map_kernel, merge_kernel
+from repro.kernels import nucleus_kernel, page_kernel, reduce_kernel
+from repro.kernels import scan_kernel, search_kernel, segment_kernel
+from repro.kernels import sort_kernel
+
+N = 2 ** 20
+VOCAB_ROWS = (8, 94208)     # internlm2_1_8b decode batch x padded vocab
+HBM_BYTES = 16 * 2 ** 30    # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent compilation cache off: a
+    compile for a described device is written but can never be read back
+    without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    with C.tuning_scope(interpret=False):
+        return jax.jit(fn).lower(*args).compile()
+
+
+def _f32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _i32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+
+#: name -> (kernel call, argument shapes given the chip's sharding)
+KERNELS = {
+    "sort": (sort_kernel.bitonic_sort, lambda s: [_f32((N,), s)]),
+    "sort_kv": (
+        lambda k, v: sort_kernel.bitonic_sort_kv(k, v, tie_break=True),
+        lambda s: [_f32((N,), s), _i32((N,), s)]),
+    "kway_merge": (
+        lambda x, c: merge_kernel.kway_merge(x, 4, counts=c),
+        lambda s: [_f32((N,), s), _i32((4,), s)]),
+    "topk": (lambda x: sort_kernel.bitonic_topk_batched(x, 50),
+             lambda s: [_f32(VOCAB_ROWS, s)]),
+    "nucleus": (lambda x: nucleus_kernel.nucleus_mask_blocks(x, top_p=0.9),
+                lambda s: [_f32(VOCAB_ROWS, s)]),
+    "reduce": (
+        lambda x: reduce_kernel.reduce_blocks(
+            jnp.square, jnp.add, x, unit=0.0),
+        lambda s: [_f32((N,), s)]),
+    "histogram": (
+        lambda x: hist_kernel.minmax_histogram_blocks(x, 1024, -4.0, 4.0),
+        lambda s: [_f32((N,), s)]),
+    "scan": (lambda x: scan_kernel.scan_blocks(jnp.add, x, unit=0),
+             lambda s: [_i32((N,), s)]),
+    "segmented_scan": (
+        lambda v, o: segment_kernel.segmented_scan_blocks(
+            jnp.add, v, o, unit=0.0),
+        lambda s: [_f32((N,), s), _i32((65,), s)]),
+    "searchsorted": (
+        lambda h, q: search_kernel.searchsorted_blocks(h, q, side="right"),
+        lambda s: [_f32((N,), s), _f32((4096,), s)]),
+    "map": (lambda x: map_kernel.map_blocks(lambda a: a * 2.0 + 1.0, x),
+            lambda s: [_f32((N,), s)]),
+    "page_gather": (
+        page_kernel.page_gather_blocks,
+        lambda s: [jax.ShapeDtypeStruct((512, 16, 8, 128), jnp.bfloat16,
+                                        sharding=s),
+                   _i32((8, 32), s)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = KERNELS[name]
+    compiled = _compile(fn, *shapes(one_chip))
+    assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_internlm2_decode_step_fits_v5e(one_chip):
+    """The engine's decode step at published widths (24 layers, d=2048,
+    16/8 heads, vocab padded to 94208), 8 slots x 4096 cache, compiles
+    for one chip and fits its HBM."""
+    from repro.configs import load_config
+    from repro.launch import engine
+    from repro.models import model as M
+
+    cfg = load_config("internlm2_1_8b")
+    on_chip = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,  # noqa: E731
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(on_chip, M.cache_specs(cfg, batch=8,
+                                                 cache_len=4096))
+    compiled = engine._decode_jit.lower(
+        params, _i32((8, 1), one_chip), caches, _i32((8,), one_chip),
+        cfg=cfg,
+    ).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
