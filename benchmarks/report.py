@@ -281,7 +281,7 @@ def obs_table(json_path=None):
                 f"`PYTHONPATH=src python -m benchmarks.serving`)")
     lines = [
         "| arch | tokens identical | launches (attributed) | trace spans "
-        "(ak.* / attributed) | instants | preempt/retries/faults |",
+        "(ak.*) | instants | preempt/retries/faults |",
         "|---|---|---|---|---|---|",
     ]
     try:
@@ -299,8 +299,7 @@ def obs_table(json_path=None):
                 f"| {e.get('arch')} | "
                 f"{'yes' if ob.get('tokens_identical') else 'NO'} | "
                 f"{launches} | {ob.get('trace_spans')} "
-                f"({ob.get('primitive_spans')} / "
-                f"{ob.get('attributed_spans')}) | "
+                f"({ob.get('primitive_spans')}) | "
                 f"{len(ob.get('instants') or [])} | "
                 f"{ob.get('preemptions')}/{ob.get('step_retries')}/"
                 f"{ob.get('faults_injected')} |"

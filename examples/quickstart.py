@@ -18,8 +18,8 @@ still match the contiguous reference bit for bit. ``--deadline`` /
 
 ``--trace PATH`` exports the telemetry walkthrough's span buffer as
 Perfetto/Chrome-trace JSON — open it at https://ui.perfetto.dev to see
-nested ``ak.*`` primitive spans carrying launch counts and modelled HBM
-bytes (DESIGN.md §11). Without the flag the walkthrough still runs and
+nested ``ak.*`` primitive spans carrying the backend and size of each
+dispatch (DESIGN.md §11). Without the flag the walkthrough still runs and
 writes to a temp file.
 
 ``--co-sort`` appends the heterogeneous co-processing vignette
@@ -133,7 +133,7 @@ print(f"autotuned sort    : {entry['backend']} {entry['knobs']} "
 # -- telemetry: spans, metrics, and a Perfetto trace ------------------------
 # One global flag gates everything: disabled (the default) costs a single
 # read per call site; enabled, every registry dispatch opens a span that
-# records backend, launch count and modelled HBM bytes (DESIGN.md §11).
+# records its backend and size (DESIGN.md §11).
 ak.telemetry.enable()
 with ak.telemetry.span("quickstart.walkthrough", cat="example"):
     ak.merge_sort(x)

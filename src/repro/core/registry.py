@@ -61,23 +61,6 @@ from repro.kernels import ref as kref
 from repro.runtime import metrics, telemetry
 
 
-def _modelled_bytes(operands) -> int:
-    """Modelled HBM traffic of one dispatch: 2x the summed operand footprint
-    (stream every array in once, write a result of comparable size) — the
-    trace-annotation lower bound; benchmarks/cost.py holds the calibrated
-    per-kernel models."""
-    total = 0
-    for a in operands:
-        size = getattr(a, "size", 0)
-        dt = getattr(a, "dtype", None)
-        if size and dt is not None:
-            try:
-                total += int(size) * np.dtype(dt).itemsize
-            except TypeError:
-                pass
-    return 2 * total
-
-
 # --------------------------------------------------------------------------
 # Tuning table
 # --------------------------------------------------------------------------
@@ -530,20 +513,12 @@ class Primitive:
             switch_below = tune["switch_below"]
         resolved = self._select_backend(backend, n, switch_below, hint)
 
-        # Telemetry span per dispatch (DESIGN.md §11), annotated with the
-        # modelled HBM streaming bytes — 2x the operand footprint (one read
-        # + one write per array; benchmarks/cost.py owns the precise
-        # per-kernel models). Disabled path: ``span("")`` is the shared
-        # no-op singleton and the bytes are never computed.
-        if telemetry.enabled():
-            cm = telemetry.span("ak." + self.name, cat="primitive",
-                                backend=resolved, n=int(n))
-            mb = _modelled_bytes(operands)
-        else:
-            cm, mb = telemetry.span(""), 0
-        with cm:
-            if mb:
-                telemetry.attribute(modelled_bytes=mb)
+        # Telemetry span per dispatch (DESIGN.md §11); with telemetry off
+        # and no profile recording, no span is built at all.
+        if not telemetry.active():
+            return self._dispatch(operands, opts, resolved, tune)
+        with telemetry.span("ak." + self.name, cat="primitive",
+                            backend=resolved, n=int(n)):
             return self._dispatch(operands, opts, resolved, tune)
 
     def _dispatch(self, operands, opts, resolved: str, tune: dict):

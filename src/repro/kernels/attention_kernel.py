@@ -100,6 +100,7 @@ def flash_attention(q, k, v, *, causal=True):
     grid = (BH, sq_p // BQ, sk_p // BK)
     out = C.pallas_call(
         functools.partial(_flash_body, scale, causal, Sk),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BQ, hd), lambda bh, iq, ik: (bh, iq, 0)),

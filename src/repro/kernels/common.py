@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.runtime import telemetry
-
 # TPU vector-register geometry (v4/v5): 8 sublanes x 128 lanes.
 SUBLANES = 8
 LANES = 128
@@ -119,13 +117,11 @@ def tuning_scope(*, interpret=None, block_rows=None, block_cols=None,
 # launches through ``pallas_call`` below; ``sort_kernel`` re-exports the
 # counter so existing callers keep working.
 #
-# Launches are attributed two ways: (a) to the label set by the innermost
+# Launches are attributed to the label set by the innermost
 # ``launch_attribution(label)`` scope — the registry opens one per primitive
-# trace, so ``launch_counts()`` breaks the total down per primitive — and
-# (b) to every open telemetry span on the calling thread, so phase spans on
-# the trace carry their aggregate launch count (DESIGN.md §11). The label
-# scope is thread-local; the tallies live under one lock because jax may
-# retrace the same program from several threads.
+# trace, so ``launch_counts()`` breaks the total down per primitive. The
+# label scope is thread-local; the tallies live under one lock because jax
+# may retrace the same program from several threads.
 # --------------------------------------------------------------------------
 
 _launch_lock = threading.Lock()
@@ -165,16 +161,18 @@ def launch_attribution(label: str):
         _launch_label.value = prev
 
 
-def pallas_call(*args, **kwargs):
+def pallas_call(*args, name: str, **kwargs):
     """Counted ``pl.pallas_call`` — every kernel in this package launches
-    through here so trace-time launch counting covers the whole suite."""
+    through here so trace-time launch counting covers the whole suite.
+    ``name`` is the kernel's role (``bitonic_inblock``, ``nucleus_cut``):
+    the compiled operation takes it as its name, so a profile's device ops
+    say which kernel ran."""
     global _launches
     label = getattr(_launch_label, "value", None) or "unattributed"
     with _launch_lock:
         _launches += 1
         _launch_by_label[label] = _launch_by_label.get(label, 0) + 1
-    telemetry.attribute(launches=1)
-    return pl.pallas_call(*args, **kwargs)
+    return pl.pallas_call(*args, name=name, **kwargs)
 
 
 def xor_partner(x: jax.Array, d: int, axis: int) -> jax.Array:

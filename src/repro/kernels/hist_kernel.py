@@ -111,6 +111,7 @@ def minmax_histogram_blocks(
 
     hist, mn, mx = C.pallas_call(
         functools.partial(_hist_body, nbins, n),
+        name="histogram",
         grid=grid,
         in_specs=[pl.BlockSpec((br, bc), lambda i: (i, 0)), smem, smem],
         out_specs=[pl.BlockSpec((1, nbp), lambda i: (0, 0)), acc, acc],

@@ -46,6 +46,7 @@ def map_blocks(f, *arrays: jax.Array, out_dtype=None) -> jax.Array:
 
     out = C.pallas_call(
         functools.partial(_map_body, f, len(views)),
+        name="map",
         grid=grid,
         in_specs=[spec] * len(views),
         out_specs=spec,

@@ -164,6 +164,7 @@ def nucleus_mask_blocks(lg, *, top_p):
     vmem = min(max(_VMEM_BLOCKS * br * vp * 4, _VMEM_FLOOR), _VMEM_CAP)
     key, idx = C.pallas_call(
         functools.partial(_nucleus_body, top_p, n),
+        name="nucleus_cut",
         grid=(bp // br,),
         in_specs=[spec, spec],
         out_specs=[cut_spec, cut_spec],

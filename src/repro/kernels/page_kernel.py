@@ -68,6 +68,7 @@ def page_gather_blocks(pages, block_table):
     )
     out = C.pallas_call(
         _gather_body,
+        name="page_gather",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, ps, D), pages.dtype),
         interpret=C.interpret_mode(),

@@ -89,6 +89,7 @@ def reduce_blocks(f, op, *arrays: jax.Array, unit, out_dtype=None) -> jax.Array:
 
     out = C.pallas_call(
         functools.partial(_reduce_body, f, op, unit, len(views)),
+        name="reduce",
         grid=grid,
         in_specs=[spec] * len(views),
         out_specs=pl.BlockSpec((_ACC_ROWS, _ACC_COLS), lambda i: (0, 0)),

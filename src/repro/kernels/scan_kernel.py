@@ -100,6 +100,7 @@ def scan_blocks(op, x: jax.Array, *, unit, exclusive: bool = False) -> jax.Array
 
     out = C.pallas_call(
         functools.partial(_scan_body, op, unit),
+        name="scan",
         grid=grid,
         in_specs=[spec],
         out_specs=spec,

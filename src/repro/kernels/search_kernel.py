@@ -89,6 +89,7 @@ def searchsorted_blocks(
     tile = pl.BlockSpec((_Q_TILE, 1), lambda qi, hj: (qi, 0))
     out = C.pallas_call(
         functools.partial(_search_body, strict, n_hay),
+        name="search",
         grid=grid,
         in_specs=[tile, pl.BlockSpec((br, bc), lambda qi, hj: (hj, 0))],
         out_specs=tile,

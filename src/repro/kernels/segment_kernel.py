@@ -169,6 +169,7 @@ def segmented_scan_blocks(op, values, offsets, *, unit,
 
     out = C.pallas_call(
         functools.partial(_segscan_body, op, unit),
+        name="segmented_scan",
         grid=grid,
         in_specs=[spec, spec],
         out_specs=spec,

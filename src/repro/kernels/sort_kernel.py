@@ -77,10 +77,6 @@ launch_counts = C.launch_counts
 reset_launch_count = C.reset_launch_count
 
 
-def _pallas_call(*args, **kwargs):
-    return C.pallas_call(*args, **kwargs)
-
-
 def _geometry() -> tuple[int, int, int]:
     """Live (rows, cols, block) from the tuning scope; the network needs a
     power-of-two block."""
@@ -251,7 +247,8 @@ def _stages_upto_block(k, block):
     return out
 
 
-def _run_inblock(stages, keys2d, vals2d, tie_break, n_blocks, rows, cols):
+def _run_inblock(stages, keys2d, vals2d, tie_break, n_blocks, rows, cols,
+                 role):
     has_vals = vals2d is not None
     spec = pl.BlockSpec((rows, cols), lambda i: (i, 0))
     specs = [spec] * (2 if has_vals else 1)
@@ -260,9 +257,10 @@ def _run_inblock(stages, keys2d, vals2d, tie_break, n_blocks, rows, cols):
         + ([jax.ShapeDtypeStruct(vals2d.shape, vals2d.dtype)] if has_vals
            else [])
     )
-    res = _pallas_call(
+    res = C.pallas_call(
         functools.partial(_inblock_body, stages, tie_break, has_vals,
                           rows * cols),
+        name=f"{role}_inblock",
         grid=(n_blocks,),
         in_specs=specs,
         out_specs=specs if has_vals else specs[0],
@@ -274,7 +272,7 @@ def _run_inblock(stages, keys2d, vals2d, tie_break, n_blocks, rows, cols):
 
 
 def _run_hyper(k, window, tail, keys2d, vals2d, tie_break, n_blocks,
-               rows, cols):
+               rows, cols, role):
     """One fused cross launch for ``window`` = consecutive halving block
     distances [d, d/2, …, S]. The (n_blocks·rows, cols) arrays are viewed as
     (Q, H, S, rows, cols) — a pure reshape: block g = q·(H·S) + t·S + r maps
@@ -294,9 +292,10 @@ def _run_hyper(k, window, tail, keys2d, vals2d, tie_break, n_blocks,
     spec = pl.BlockSpec((1, H, 1, rows, cols), lambda q, r: (q, 0, r, 0, 0))
     ins = [view(keys2d)] + ([view(vals2d)] if has_vals else [])
     outs = [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in ins]
-    res = _pallas_call(
+    res = C.pallas_call(
         functools.partial(_hyper_body, k, H, S, tail, tie_break, has_vals,
                           block),
+        name=f"{role}_cross",
         grid=(Q, S),
         in_specs=[spec] * len(ins),
         out_specs=[spec] * len(ins) if has_vals else spec,
@@ -417,7 +416,11 @@ def _sort_network(k2d, v2d, total, tie_break, *, rows, cols, first_k=2):
     block view. ``first_k=2`` is the full sort. ``first_k=2·L`` resumes the
     network on data that is already L-run alternating-sorted — this is the
     k-way merge tail used by ``kernels/merge_kernel.py``: only the merge
-    phases run, the log²-depth build phases below ``first_k`` are skipped."""
+    phases run, the log²-depth build phases below ``first_k`` are skipped.
+    Its kernels are named for that role: ``bitonic_inblock`` and
+    ``bitonic_cross`` in a sort, ``merge_inblock`` and ``merge_cross`` in
+    a merge."""
+    role = "bitonic" if first_k == 2 else "merge"
     block = rows * cols
     n_blocks = total // block
     hyper = _hyper_order()
@@ -430,7 +433,7 @@ def _sort_network(k2d, v2d, total, tie_break, *, rows, cols, first_k=2):
         k *= 2
     if stages:
         k2d, v2d = _run_inblock(stages, k2d, v2d, tie_break, n_blocks,
-                                rows, cols)
+                                rows, cols, role)
     # (when first_k > block the loop above never ran and k == first_k: the
     # cross loop starts directly at the first merge phase)
     # Phase 2: k > block — cross stages at block distances k/(2·block) … 1,
@@ -446,9 +449,10 @@ def _sort_network(k2d, v2d, total, tie_break, *, rows, cols, first_k=2):
         if hyper <= 0:
             for d in dists:
                 k2d, v2d = _run_hyper(k, [d], [], k2d, v2d, tie_break,
-                                      n_blocks, rows, cols)
+                                      n_blocks, rows, cols, role)
             k2d, v2d = _run_inblock(_stages_upto_block(k, block), k2d,
-                                    v2d, tie_break, n_blocks, rows, cols)
+                                    v2d, tie_break, n_blocks, rows, cols,
+                                    role)
         else:
             idx = 0
             while idx < len(dists):
@@ -460,7 +464,7 @@ def _sort_network(k2d, v2d, total, tie_break, *, rows, cols, first_k=2):
                 tail = (_stages_upto_block(k, block)
                         if idx == len(dists) else [])
                 k2d, v2d = _run_hyper(k, window, tail, k2d, v2d, tie_break,
-                                      n_blocks, rows, cols)
+                                      n_blocks, rows, cols, role)
         k *= 2
     return k2d, v2d
 

@@ -528,7 +528,10 @@ class Engine:
             bt = held = None
         self.pool = pool
         cur_tok = jnp.zeros((B, 1), jnp.int32)
-        pos = np.full((B,), self.cache_len, np.int32)   # parked lanes
+        # parked lanes. Updated in place while the step that was handed it
+        # may still be queued, and the CPU backend reads (or aliases) a
+        # numpy argument when the step runs: each step gets a copy
+        pos = np.full((B,), self.cache_len, np.int32)
         slot_rid: list = [None] * B                     # host slot map
         budget: dict[int, int] = {}                     # rid -> max tokens
         emitted: dict[int, int] = {}                    # rid -> bookkept
@@ -1096,12 +1099,13 @@ class Engine:
                         np.minimum(bt, self.num_pages - 1))
                     logits, caches = supervised(
                         "engine.decode", self._decode_paged,
-                        self.params, cur_tok, caches, jnp.asarray(pos),
-                        bt_dev)
+                        self.params, cur_tok, caches,
+                        jnp.asarray(pos.copy()), bt_dev)
                 else:
                     logits, caches = supervised(
                         "engine.decode", self._decode,
-                        self.params, cur_tok, caches, jnp.asarray(pos))
+                        self.params, cur_tok, caches,
+                        jnp.asarray(pos.copy()))
                 rids = np.asarray(
                     [-1 if r is None else r for r in slot_rid], np.int32)
                 idxs = np.asarray(

@@ -14,20 +14,25 @@ JSON file that https://ui.perfetto.dev opens directly. Point events
 *instant* events (``ph: "i"``); per-request lifetime tracks are nestable
 *async* events (``ph: "b"``/``"e"`` keyed by request id).
 
-**Attribution**: ``attribute(launches=, modelled_bytes=)`` adds to every
-span on the calling thread's open stack. The ``kernels/common.pallas_call``
-wrapper attributes each launch and ``core/registry`` attributes modelled
-HBM bytes, so an ``engine.decode`` span shows the aggregate launch count
-and modelled roofline bytes of everything traced under it.
+**The profiler's clock**: while the JAX profiler records
+(``jax.profiler.start_trace`` ... ``stop_trace``), every ``span(name,
+**args)`` also opens a ``jax.profiler.TraceAnnotation(name, **args)``, ring
+buffer on or off. The span then lands on the profile's host plane beside
+the device's programs and operations, with its args as metadata
+(``step``, ``rid``, ``resume``), so a profile reader can put each device
+gap under the engine phase the host was in. The device's clock in a
+profile may read up to about a millisecond off the host's: join host
+spans to device events by order, not by time.
 
 **Overhead contract** (gated by the ``serve.obs`` benchmark): telemetry is
 OFF by default; every public entry point starts with one module-global
-read and returns a shared no-op (``span()`` hands back the *same*
-``_NoopSpan`` singleton every call — no allocation, no lock, no clock
-read). Enabling must not change computed results: spans only observe.
+read and, for ``span()``, one check of whether a profile records
+(``active()`` makes both). With neither on, ``span()`` hands back the
+*same* ``_NoopSpan`` singleton every call — no allocation, no lock, no
+clock read. Enabling must not change computed results: spans only observe.
 
-stdlib-only on purpose — this module is imported by ``kernels/common.py``
-and must carry no jax/numpy weight.
+stdlib-only at import, so any module can import it without a cycle;
+JAX's profiler is imported on the first span, never at module load.
 """
 from __future__ import annotations
 
@@ -132,15 +137,25 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, on first use
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first span."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
 class _Span:
-    __slots__ = ("name", "cat", "args", "t0", "tid", "launches", "mbytes")
+    __slots__ = ("name", "cat", "args", "t0", "tid", "annotation")
 
     def __init__(self, name: str, cat: str, args: dict):
         self.name = name
         self.cat = cat
         self.args = args
-        self.launches = 0
-        self.mbytes = 0
 
     def __enter__(self):
         stack = getattr(_tls, "stack", None)
@@ -148,55 +163,54 @@ class _Span:
             stack = _tls.stack = []
         stack.append(self)
         self.tid = _tid()
+        ann = _profiler_annotation()
+        self.annotation = (ann(self.name, **self.args)
+                           if ann.is_enabled() else None)
+        if self.annotation is not None:
+            self.annotation.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
         end = _now_us()
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
         if not _enabled:        # disabled mid-span: drop silently
             return False
-        args = dict(self.args)
-        if self.launches:
-            args["launches"] = self.launches
-        if self.mbytes:
-            args["modelled_bytes"] = self.mbytes
         ev = {"name": self.name, "cat": self.cat, "ph": "X",
               "ts": self.t0, "dur": end - self.t0,
               "pid": 0, "tid": self.tid}
-        if args:
-            ev["args"] = args
+        if self.args:
+            ev["args"] = dict(self.args)
         _record(ev)
         return False
 
 
+def active() -> bool:
+    """Whether a span records anywhere: the ring buffer is enabled or a
+    profile is recording."""
+    return _enabled or _profiler_annotation().is_enabled()
+
+
 def span(name: str, cat: str = "span", **args):
-    """Context manager timing a nested phase. When telemetry is disabled
-    this returns the shared no-op singleton."""
-    if not _enabled:
-        return _NOOP
-    return _Span(name, cat, args)
+    """Context manager timing a nested phase: into the ring buffer when
+    telemetry is enabled, into the profile when one is recording. With
+    neither, the shared no-op singleton."""
+    if _enabled:
+        return _Span(name, cat, args)
+    ann = _profiler_annotation()
+    if ann.is_enabled():
+        return ann(name, **args)
+    return _NOOP
 
 
 def current_span() -> str | None:
     """Name of the innermost open span on this thread (None outside)."""
     stack = getattr(_tls, "stack", None)
     return stack[-1].name if stack else None
-
-
-def attribute(launches: int = 0, modelled_bytes: int = 0) -> None:
-    """Credit work to EVERY open span on this thread, so parent phase
-    spans aggregate their children's launches and modelled HBM bytes."""
-    if not _enabled:
-        return
-    stack = getattr(_tls, "stack", None)
-    if not stack:
-        return
-    for s in stack:
-        s.launches += launches
-        s.mbytes += modelled_bytes
 
 
 # -- point + async events ---------------------------------------------------

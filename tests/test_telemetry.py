@@ -2,16 +2,19 @@
 
 Covers the PR-9 acceptance criteria (DESIGN.md §11):
   * span nesting/ordering: parent complete-events contain their children
-    in time, exit order is recorded innermost-first, and attribution
-    (launches / modelled bytes) aggregates bottom-up onto every open
-    span — property-tested over random span trees when hypothesis is
-    available, with a deterministic fallback tree either way;
+    in time, exit order is recorded innermost-first, and every span keeps
+    its own args — property-tested over random span trees when hypothesis
+    is available, with a deterministic fallback tree either way;
+  * the profiler's clock: a span opened while ``jax.profiler`` records
+    lands by name, with its args, on the profile's host plane inside the
+    profile's window, with the ring buffer on or off;
   * golden Perfetto/Chrome-trace schema: exported docs carry the
     displayTimeUnit + process/thread metadata the viewer needs, every
     event passes ``validate_trace``, and structurally broken docs are
     rejected with ``ValueError``;
-  * disabled-mode no-op contract: ``span()`` returns ONE shared no-op
-    singleton, nothing is buffered, ``attribute``/``instant`` are free;
+  * disabled-mode no-op contract: with the ring buffer off and no profile
+    recording, ``span()`` returns ONE shared no-op singleton, nothing is
+    buffered, ``instant`` is free;
   * Prometheus round-trip: ``parse_prometheus(prometheus_text())``
     reproduces every counter/gauge/histogram sample the snapshot holds,
     including labels, escapes, and the cumulative bucket form;
@@ -24,6 +27,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 
 import pytest
 
@@ -46,12 +50,14 @@ def _clean_telemetry():
 
 def test_disabled_span_is_shared_singleton():
     assert not telemetry.enabled()
+    assert not telemetry.active()
     s1 = telemetry.span("a", cat="x", foo=1)
     s2 = telemetry.span("b")
     assert s1 is s2  # no allocation per call on the disabled path
+    assert s1 is telemetry._NOOP
     with s1:
-        with telemetry.span("nested"):
-            telemetry.attribute(launches=3, modelled_bytes=100)
+        with telemetry.span("nested", step=3):
+            pass
         telemetry.instant("boom")
         telemetry.async_begin("req", 7)
         telemetry.async_end("req", 7)
@@ -61,16 +67,71 @@ def test_disabled_span_is_shared_singleton():
 
 def test_disabled_records_nothing_into_metrics_registry():
     before = json.dumps(metrics.snapshot(), sort_keys=True)
-    with telemetry.span("a"):
-        telemetry.attribute(launches=5)
+    with telemetry.span("a", step=5):
+        telemetry.instant("tick")
     assert json.dumps(metrics.snapshot(), sort_keys=True) == before
 
 
 def test_disable_mid_span_drops_the_event():
     telemetry.enable()
-    with telemetry.span("outer"):
+    with telemetry.span("outer", step=1):
         telemetry.disable()
     assert all(e["name"] != "outer" for e in telemetry.events())
+    assert telemetry.span("after") is telemetry._NOOP
+
+
+# --------------------------------------------------------------------------
+# The profiler's clock
+# --------------------------------------------------------------------------
+
+def _profile(tmp_path, body):
+    """Run ``body`` while ``jax.profiler`` records; returns the profile's
+    host events as (start_ns, duration_ns, name, stats), start_ns from the
+    profile's start, and the longest the profile can have lasted."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    t0 = time.perf_counter_ns()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    longest = time.perf_counter_ns() - t0
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [(e.start_ns, e.duration_ns, e.name, dict(e.stats))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    return events, longest
+
+
+@pytest.mark.parametrize("ring_buffer", [False, True])
+def test_span_lands_on_the_profile_host_plane(tmp_path, ring_buffer):
+    """A span opened while the profiler records appears by name on the
+    profile's host plane, inside the profile's window, with its args as
+    metadata — with the ring buffer off (the benchmark's traced runs) and
+    on (then it is in both)."""
+    if ring_buffer:
+        telemetry.enable()
+
+    def body():
+        assert telemetry.active()
+        with telemetry.span("engine.admit", cat="engine", step=3, rid=7,
+                            resume=False):
+            time.sleep(0.005)
+
+    events, longest = _profile(tmp_path, body)
+    (start, dur, _, args), = [e for e in events if e[2] == "engine.admit"]
+    assert 0 <= start and start + dur <= longest
+    assert dur >= 5e6
+    assert args == {"step": 3, "rid": 7, "resume": 0}
+    kept = [e["name"] for e in telemetry.events() if e["ph"] == "X"]
+    assert kept == (["engine.admit"] if ring_buffer else [])
+    if not ring_buffer:   # the profile stopped: the shared no-op again
+        assert telemetry.span("engine.admit") is telemetry._NOOP
 
 
 # --------------------------------------------------------------------------
@@ -84,8 +145,7 @@ def _run_tree(tree, prefix="s"):
     for i, sub in enumerate(tree):
         name = f"{prefix}.{i}"
         names.append(name)
-        with telemetry.span(name, cat="test"):
-            telemetry.attribute(launches=1)
+        with telemetry.span(name, cat="test", depth=name.count(".")):
             names.extend(_run_tree(sub, prefix=name))
     return names
 
@@ -103,9 +163,8 @@ def _check_tree_invariants(opened):
             p = by_name[parent]
             assert p["ts"] <= e["ts"]
             assert e["ts"] + e["dur"] <= p["ts"] + p["dur"]
-        # ...and aggregate their launches: 1 (own) + descendants'
-        n_desc = sum(1 for o in opened if o.startswith(name + "."))
-        assert e["args"]["launches"] == 1 + n_desc
+        # ...and each keeps its own args, none of its children's
+        assert e["args"] == {"depth": name.count(".")}
     # complete events are recorded at EXIT: children before parents
     order = [e["name"] for e in evs]
     for name in order:
@@ -175,9 +234,8 @@ def test_spans_from_threads_get_distinct_tids():
     barrier = threading.Barrier(3)
 
     def work(tag):
-        with telemetry.span(tag):
+        with telemetry.span(tag, rid=int(tag[1:])):
             barrier.wait(timeout=30)
-            telemetry.attribute(launches=1)
 
     ts = [threading.Thread(target=work, args=(f"t{i}",)) for i in range(3)]
     for t in ts:
@@ -187,8 +245,8 @@ def test_spans_from_threads_get_distinct_tids():
     evs = {e["name"]: e for e in telemetry.events()}
     assert len(evs) == 3
     assert len({e["tid"] for e in evs.values()}) == 3
-    # attribution is thread-local: each span got exactly its own launch
-    assert all(e["args"]["launches"] == 1 for e in evs.values())
+    # the span stack is thread-local: each span kept exactly its own args
+    assert all(e["args"] == {"rid": int(n[1:])} for n, e in evs.items())
 
 
 # --------------------------------------------------------------------------
@@ -199,8 +257,9 @@ def test_exported_doc_matches_golden_schema(tmp_path):
     telemetry.enable()
     telemetry.async_begin("req", 3, rid=3)
     with telemetry.span("phase", cat="engine", step=0):
-        with telemetry.span("ak.sort", cat="primitive"):
-            telemetry.attribute(launches=2, modelled_bytes=4096)
+        with telemetry.span("ak.sort", cat="primitive", backend="pallas",
+                            n=4096):
+            pass
         telemetry.instant("fault-injected", cat="fault", site="pool.alloc")
     telemetry.async_end("req", 3, status="COMPLETED")
     telemetry.disable()
@@ -228,7 +287,7 @@ def test_exported_doc_matches_golden_schema(tmp_path):
     assert inst["s"] == "t" and inst["args"]["site"] == "pool.alloc"
     assert by_ph["b"][0]["id"] == "3" and by_ph["e"][0]["id"] == "3"
     sort_span = next(e for e in by_ph["X"] if e["name"] == "ak.sort")
-    assert sort_span["args"] == {"launches": 2, "modelled_bytes": 4096}
+    assert sort_span["args"] == {"backend": "pallas", "n": 4096}
 
 
 @pytest.mark.parametrize("breakage", [
@@ -374,7 +433,8 @@ def test_launch_counter_is_thread_safe_and_per_label():
     def work(label, n):
         with KC.launch_attribution(label):
             for _ in range(n):
-                KC.pallas_call(kernel, out_shape=shape, interpret=True)
+                KC.pallas_call(kernel, name="noop", out_shape=shape,
+                               interpret=True)
 
     ts = [threading.Thread(target=work, args=(f"prim{i % 2}", 50))
           for i in range(4)]
@@ -382,7 +442,8 @@ def test_launch_counter_is_thread_safe_and_per_label():
         t.start()
     for t in ts:
         t.join()
-    KC.pallas_call(kernel, out_shape=shape, interpret=True)  # bare launch
+    KC.pallas_call(kernel, name="noop", out_shape=shape,
+                   interpret=True)  # bare launch
     counts = KC.launch_counts()
     assert counts["prim0"] == counts["prim1"] == 100
     assert counts["unattributed"] == 1
@@ -396,6 +457,8 @@ def test_launch_counter_is_thread_safe_and_per_label():
 
 
 def test_registry_dispatch_spans_carry_attribution():
+    """The registry's dispatch span carries the backend it resolved and
+    the size it dispatched on."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -410,9 +473,7 @@ def test_registry_dispatch_spans_carry_attribution():
     spans = [e for e in telemetry.events()
              if e["ph"] == "X" and e["name"] == "ak.sort"]
     assert spans, "registry dispatch recorded no primitive span"
-    assert spans[0]["args"]["launches"] > 0
-    # modelled bytes: 2 (read+write) * n * itemsize
-    assert spans[0]["args"]["modelled_bytes"] == 2 * 2048 * 4
+    assert spans[0]["args"] == {"backend": "pallas", "n": 2048}
     # and the snapshot's registry counters agree with the legacy accessor
     snap = telemetry.snapshot()["metrics"]
     calls = {s["labels"]["primitive"]: s["value"]

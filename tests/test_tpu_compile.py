@@ -10,6 +10,8 @@ and only the worker that runs this file loads the TPU library.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -104,11 +106,40 @@ KERNELS = {
 }
 
 
+#: name -> the roles its Pallas kernels are named for in the compiled
+#: program (the operation names a profile shows)
+ROLES = {
+    "sort": ("bitonic_inblock", "bitonic_cross"),
+    "sort_kv": ("bitonic_inblock", "bitonic_cross"),
+    "kway_merge": ("merge_cross",),
+    "topk": ("bitonic_inblock", "bitonic_cross"),
+    "nucleus": ("nucleus_cut",),
+    "reduce": ("reduce",),
+    "histogram": ("histogram",),
+    "scan": ("scan",),
+    "segmented_scan": ("segmented_scan",),
+    "searchsorted": ("search",),
+    "map": ("map",),
+    "page_gather": ("page_gather",),
+}
+
+
+def _kernel_names(text: str) -> set:
+    """Names of the Pallas kernels' operations in a compiled program."""
+    return {m.group(1) for m in re.finditer(
+        r"^\s*%([\w.\-]+) = .*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)}
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = KERNELS[name]
     compiled = _compile(fn, *shapes(one_chip))
-    assert "tpu_custom_call" in compiled.as_text(), name
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, name
+    names = _kernel_names(text)
+    for role in ROLES[name]:
+        assert any(role in n for n in names), (role, sorted(names))
 
 
 def test_internlm2_decode_step_fits_v5e(one_chip):
