@@ -171,6 +171,14 @@ def blockwise_attention(q, k, v, *, causal, q_offset=0, chunk=1024,
     return out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd).astype(q.dtype)
 
 
+def _put(leaf, new, start):
+    """``new`` (cast to the leaf's dtype, with leading unit axes added)
+    written into ``leaf`` at ``start``."""
+    new = new.astype(leaf.dtype)
+    return jax.lax.dynamic_update_slice(
+        leaf, new.reshape((1,) * (leaf.ndim - new.ndim) + new.shape), start)
+
+
 def attention_apply(
     p,
     cfg,
@@ -184,6 +192,8 @@ def attention_apply(
     cache_index=None,
     block_table=None,
     page_size=None,
+    layer=None,
+    refill_row=None,
     use_rope=True,
     chunk=1024,
     unroll=False,
@@ -209,6 +219,18 @@ def attention_apply(
     gather). Stale bytes in unwritten page tails are hidden by the same
     per-row attention-length mask as the contiguous path, so the math is
     position-for-position identical to the contiguous cache.
+
+    STACKED cache: with ``layer`` (an int32 index), the leaves are the
+    whole model's ``(L, ...)`` stacks of either form. This step's K/V
+    columns are written into row ``layer`` alone and the layer is read
+    back for attention, so a layer scan can carry the stacks and XLA
+    updates them in place; ``new_cache`` is the updated stacks.
+
+    REFILL: with ``refill_row`` (batch-1 tokens, scalar ``cache_index``),
+    the whole of that batch row is rewritten as a fresh cache row would
+    read — zeros, with this chunk's K/V at ``cache_index`` — and attention
+    reads that row. A slot's prefill thus lands in the shared cache with
+    no batch-1 cache beside it.
     """
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     B, Sq, _ = x.shape
@@ -222,9 +244,17 @@ def attention_apply(
         k = apply_rope(k, kpos, cfg.rope_theta)
 
     new_cache = None
+    new = {"k": k, "v": v}
+    at = () if layer is None else (layer,)
+
+    def _read(leaf):
+        if layer is None:
+            return leaf
+        return jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+
     if cache is not None and block_table is not None:
         ps = int(page_size)
-        P, T = cache["k"].shape[0], block_table.shape[1]
+        P, T = cache["k"].shape[-4], block_table.shape[1]
         ci = jnp.asarray(cache_index)
         ci_v = ci if ci.ndim == 1 else jnp.broadcast_to(ci, (B,))
         cols = ci_v[:, None] + jnp.arange(Sq)[None, :]        # (B, Sq) logical
@@ -234,15 +264,14 @@ def attention_apply(
         # the allocator's sentinel) both land out of range -> drop
         phys = jnp.where(cols < T * ps, phys, P)
         offs = cols % ps
-        k = cache["k"].at[phys, offs].set(
-            k.astype(cache["k"].dtype), mode="drop"
-        )
-        v = cache["v"].at[phys, offs].set(
-            v.astype(cache["v"].dtype), mode="drop"
-        )
-        new_cache = {"k": k, "v": v}
-        k = _registry.call("page_gather", k, block_table)  # (B, T*ps, KV, hd)
-        v = _registry.call("page_gather", v, block_table)
+        new_cache = {
+            n: c.at[at + (phys, offs)].set(new[n].astype(c.dtype),
+                                           mode="drop")
+            for n, c in cache.items()
+        }
+        # (B, T*ps, KV, hd) logical view of this layer's pages
+        k = _registry.call("page_gather", _read(new_cache["k"]), block_table)
+        v = _registry.call("page_gather", _read(new_cache["v"]), block_table)
         q_offset = cache_index
         causal = True
     elif cache is not None:
@@ -253,21 +282,24 @@ def attention_apply(
             # clamped write would corrupt the last cache column)
             cols = ci[:, None] + jnp.arange(Sq)[None, :]       # (B, Sq)
             rows = jnp.arange(B)[:, None]
-            k = cache["k"].at[rows, cols].set(
-                k.astype(cache["k"].dtype), mode="drop"
-            )
-            v = cache["v"].at[rows, cols].set(
-                v.astype(cache["v"].dtype), mode="drop"
-            )
+            new_cache = {
+                n: c.at[at + (rows, cols)].set(new[n].astype(c.dtype),
+                                               mode="drop")
+                for n, c in cache.items()
+            }
+        elif refill_row is None:
+            new_cache = {n: _put(c, new[n], at + (0, ci, 0, 0))
+                         for n, c in cache.items()}
         else:
-            k = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype),
-                (0, cache_index, 0, 0)
-            )
-            v = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, cache_index, 0, 0)
-            )
-        new_cache = {"k": k, "v": v}
+            fresh = {n: _put(jnp.zeros((1,) + c.shape[-3:], c.dtype), new[n],
+                             (0, ci, 0, 0))
+                     for n, c in cache.items()}
+            new_cache = {n: _put(c, fresh[n], at + (refill_row, 0, 0, 0))
+                         for n, c in cache.items()}
+        if refill_row is None:
+            k, v = _read(new_cache["k"]), _read(new_cache["v"])
+        else:
+            k, v = fresh["k"], fresh["v"]
         # mask out not-yet-written cache slots via causal offset (per-row
         # when cache_index is the engine's per-slot position vector)
         q_offset = cache_index

@@ -11,6 +11,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.common import NEG_MASK
 from repro.models import layers as L
@@ -44,7 +45,9 @@ def _maybe_remat(fn, cfg):
 
 def scan_layers(body, carry, xs, cfg):
     """lax.scan over stacked layer params — or a Python unroll when the
-    config is in cost-model mode (see ModelConfig.unroll_layers)."""
+    config is in cost-model mode (see ModelConfig.unroll_layers). The
+    unroll indexes a numpy leaf of ``xs`` on the host, so a layer index
+    given as one reaches the body as a static integer."""
     if not cfg.unroll_layers:
         return jax.lax.scan(body, carry, xs)
     n = jax.tree.leaves(xs)[0].shape[0]
@@ -413,10 +416,12 @@ def decode_step(params, cfg, tokens, caches, position, *, chunk=1024,
 
 
 def _decode(params, cfg, tokens, caches, position, *, chunk=1024,
-            block_tables=None, page_size=None):
+            block_tables=None, page_size=None, refill_row=None):
     """Cache-stepping forward for any query length: S=1 is the decode step,
     S=prompt_len with zeroed caches and position=0 is the prefill (the KV
-    writes land in slots [0, S) and causal masking hides the empty tail)."""
+    writes land in slots [0, S) and causal masking hides the empty tail).
+    ``refill_row`` (dense/moe): batch-1 tokens rewrite that row of the
+    shared caches whole, as a prefill into zeroed caches would leave it."""
     B, S = tokens.shape
     x = L.embed(params["embed"], tokens)
     # scalar position -> (S,) shared positions; (B,) vector -> (B, S)
@@ -424,44 +429,35 @@ def _decode(params, cfg, tokens, caches, position, *, chunk=1024,
     fam = cfg.family
 
     if fam in ("dense", "moe"):
-        first_dense = fam == "moe" and cfg.first_layer_dense
+        # The stacked (L, ...) K/V ride in the scan's carry and each layer
+        # writes only its new columns into its own row: XLA updates the
+        # donated cache in place. As scan xs/ys they would be rebuilt in
+        # fresh whole-cache buffers and copied back every step.
+        def block(p, x, kv, layer, dense):
+            kw = dict(cache=kv, cache_index=position, layer=layer,
+                      block_table=block_tables, page_size=page_size,
+                      refill_row=refill_row, chunk=chunk)
+            if dense:
+                return T.dense_block(p, cfg, x, positions, **kw)
+            x, _, nc = T.moe_block(p, cfg, x, positions, use_ep=False, **kw)
+            return x, nc
 
-        def body(x, inp):
-            p, ck, cv = inp
-            cache = {"k": ck, "v": cv}
-            if fam == "dense":
-                x, nc = T.dense_block(p, cfg, x, positions, cache=cache,
-                                      cache_index=position,
-                                      block_table=block_tables,
-                                      page_size=page_size, chunk=chunk)
-            else:
-                x, _, nc = T.moe_block(p, cfg, x, positions, cache=cache,
-                                       cache_index=position,
-                                       block_table=block_tables,
-                                       page_size=page_size, use_ep=False,
-                                       chunk=chunk)
-            return x, (nc["k"], nc["v"])
+        def body(carry, inp):
+            x, ck, cv = carry
+            p, layer = inp
+            x, nc = block(p, x, {"k": ck, "v": cv}, layer, fam == "dense")
+            return (x, nc["k"], nc["v"]), None
 
-        kvs = caches["kv"]
-        if first_dense:
-            c0 = {"k": kvs["k"][0], "v": kvs["v"][0]}
-            x, nc0 = T.dense_block(params["layer0"], cfg, x, positions,
-                                   cache=c0, cache_index=position,
-                                   block_table=block_tables,
-                                   page_size=page_size, chunk=chunk)
-            x, (nk, nv) = scan_layers(
-                body, x, (params["layers"], kvs["k"][1:], kvs["v"][1:]), cfg
-            )
-            new_kv = {
-                "k": jnp.concatenate([nc0["k"][None], nk]),
-                "v": jnp.concatenate([nc0["v"][None], nv]),
-            }
-        else:
-            x, (nk, nv) = scan_layers(
-                body, x, (params["layers"], kvs["k"], kvs["v"]), cfg
-            )
-            new_kv = {"k": nk, "v": nv}
-        new_caches = {"kv": new_kv}
+        kv = caches["kv"]
+        first = 0
+        if fam == "moe" and cfg.first_layer_dense:
+            x, kv = block(params["layer0"], x, kv, 0, True)
+            first = 1
+        layers = np.arange(first, kv["k"].shape[0], dtype=np.int32)
+        (x, nk, nv), _ = scan_layers(
+            body, (x, kv["k"], kv["v"]), (params["layers"], layers), cfg
+        )
+        new_caches = {"kv": {"k": nk, "v": nv}}
 
     elif fam == "ssm":
         def body(x, inp):
@@ -647,21 +643,25 @@ def slot_prefill(params, cfg, tokens, caches, slot, *, cache_len,
     """Prefill ONE request into slot ``slot`` of a shared decode cache.
 
     tokens: (1, S) int32 prompt (right-pad to a fixed S so the engine jits
-    this once); ``slot``: int32 batch row (traced). Runs a batch-1 prefill
-    into a fresh zero cache and writes the result into row ``slot`` of
-    every leaf of ``caches`` via a size-1 dynamic-slice update along that
-    leaf's batch axis — live neighbouring slots are untouched bit for bit,
-    and the whole slot row is overwritten (the refilled slot needs no
-    separate reset: stale K/V beyond the prompt is either rewritten by
-    later decode steps or hidden by the per-slot attention-length mask).
+    this once); ``slot``: int32 batch row (traced). The result is a batch-1
+    prefill into a fresh zero cache written into row ``slot`` of every
+    leaf of ``caches`` — live neighbouring slots are untouched bit for
+    bit, and the whole slot row is overwritten (the refilled slot needs no
+    separate reset). Dense/moe layers write their rows straight into the
+    shared cache (``_decode``'s ``refill_row``); the other families run
+    the batch-1 prefill and copy it in with a size-1 dynamic-slice update
+    along each leaf's batch axis.
 
     Returns (logits (1, S, V), new shared caches).
     """
+    slot = jnp.asarray(slot, jnp.int32)
+    if cfg.family in ("dense", "moe"):
+        return _decode(params, cfg, tokens, caches, jnp.int32(0),
+                       chunk=chunk, refill_row=slot)
     logits, fresh, _ = prefill(
         params, cfg, tokens, cache_len=cache_len, frames=frames,
         patches=patches, chunk=chunk,
     )
-    slot = jnp.asarray(slot, jnp.int32)
     new = jax.tree.map(
         lambda big, small, ax: jax.lax.dynamic_update_slice_in_dim(
             big, small.astype(big.dtype), slot, axis=ax

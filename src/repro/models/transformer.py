@@ -78,12 +78,14 @@ def cross_layer_init(rng, cfg):
 
 
 def dense_block(p, cfg, x, positions, *, cache=None, cache_index=None,
-                block_table=None, page_size=None, causal=True, chunk=1024):
+                block_table=None, page_size=None, layer=None,
+                refill_row=None, causal=True, chunk=1024):
     h, new_cache = L.attention_apply(
         p["attn"], cfg, L.rmsnorm(p["ln1"], x, cfg.norm_eps),
         positions=positions, causal=causal, cache=cache,
         cache_index=cache_index, block_table=block_table,
-        page_size=page_size, chunk=chunk, unroll=cfg.unroll_layers,
+        page_size=page_size, layer=layer, refill_row=refill_row,
+        chunk=chunk, unroll=cfg.unroll_layers,
     )
     x = x + h
     x = x + L.swiglu(p["mlp"], L.rmsnorm(p["ln2"], x, cfg.norm_eps))
@@ -92,12 +94,14 @@ def dense_block(p, cfg, x, positions, *, cache=None, cache_index=None,
 
 def moe_block(p, cfg, x, positions, *, mesh=None, dp_axes=("data",),
               cache=None, cache_index=None, block_table=None,
-              page_size=None, chunk=1024, use_ep=True):
+              page_size=None, layer=None, refill_row=None, chunk=1024,
+              use_ep=True):
     h, new_cache = L.attention_apply(
         p["attn"], cfg, L.rmsnorm(p["ln1"], x, cfg.norm_eps),
         positions=positions, causal=True, cache=cache,
         cache_index=cache_index, block_table=block_table,
-        page_size=page_size, chunk=chunk, unroll=cfg.unroll_layers,
+        page_size=page_size, layer=layer, refill_row=refill_row,
+        chunk=chunk, unroll=cfg.unroll_layers,
     )
     x = x + h
     z = L.rmsnorm(p["ln2"], x, cfg.norm_eps)

@@ -106,3 +106,88 @@ def test_cache_specs_match_zero_caches():
         assert s_def == z_def, arch
         for s, z in zip(s_flat, z_flat):
             assert s.shape == z.shape and s.dtype == z.dtype, arch
+
+
+def _random_caches(specs, seed):
+    """Caches of ``specs``' shapes filled with normal noise, so that every
+    byte a step leaves alone can be told from one it writes."""
+    leaves, tree = jax.tree.flatten(specs)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return jax.tree.unflatten(tree, [
+        jax.random.normal(k, s.shape, jnp.float32).astype(s.dtype)
+        for s, k in zip(leaves, keys)])
+
+
+#: case -> (arch, page size or None for the contiguous cache)
+WRITE_CASES = {
+    "dense": ("internlm2_1_8b", None),
+    "dense_paged": ("internlm2_1_8b", 4),
+    "moe_first_layer_dense": ("deepseek_moe_16b", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_decode_step_writes_only_its_columns(case):
+    """One decode step with per-slot positions changes each cache leaf at
+    [layer, row, pos[row]] for every layer and live row (at the (page,
+    offset) the block table names, when paged) and leaves every other
+    byte as it was: the parked row, past the cache, is untouched."""
+    arch, page_size = WRITE_CASES[case]
+    cfg = load_smoke_config(arch)
+    assert cfg.family == "dense" or cfg.first_layer_dense
+    p = M.init_params(jax.random.PRNGKey(0), cfg)
+    B, cache_len = 4, 16
+    pos = np.array([3, 9, cache_len + 2, 15], np.int32)    # row 2 parked
+    live = [b for b in range(B) if pos[b] < cache_len]
+    kw = {}
+    if page_size is None:
+        specs = M.cache_specs(cfg, batch=B, cache_len=cache_len)
+        where = [(b, pos[b]) for b in live]
+    else:
+        T = cache_len // page_size
+        num_pages = B * T + 3
+        table = np.random.RandomState(1).permutation(num_pages)[: B * T]
+        table = table.reshape(B, T).astype(np.int32)
+        kw = dict(block_tables=jnp.asarray(table), page_size=page_size)
+        specs = M.paged_cache_specs(cfg, num_pages=num_pages,
+                                    page_size=page_size)
+        where = [(table[b, pos[b] // page_size], pos[b] % page_size)
+                 for b in live]
+    caches = _random_caches(specs, 2)
+    before = [np.asarray(a) for a in jax.tree.leaves(caches)]
+    tokens = jnp.arange(B, dtype=jnp.int32)[:, None] + 1
+    logits, new = M.decode_step(p, cfg, tokens, caches, jnp.asarray(pos),
+                                **kw)
+    assert np.isfinite(np.asarray(logits, np.float32)).all()
+    after = [np.asarray(a) for a in jax.tree.leaves(new)]
+    for old, got in zip(before, after):
+        assert got.shape == old.shape and got.dtype == old.dtype
+        written = np.zeros(old.shape[:3], bool)
+        for row, col in where:
+            written[:, row, col] = True
+        same = (got == old).reshape(*old.shape[:3], -1).all(-1)
+        np.testing.assert_array_equal(same, ~written, err_msg=case)
+
+
+@pytest.mark.parametrize("arch", ["deepseek_moe_16b", "granite_moe_1b"])
+def test_slot_prefill_row_equals_fresh_prefill(arch):
+    """A slot prefill writes the prompt's layers straight into the shared
+    cache: the refilled row, every other row and the logits are bitwise
+    what a batch-1 prefill into zeroed caches, copied into the row, gives
+    (the MoE layer stacks, with and without a dense first layer;
+    tests/test_engine.py checks the dense one)."""
+    cfg = load_smoke_config(arch)
+    p = M.init_params(jax.random.PRNGKey(0), cfg)
+    B, S, cache_len, slot = 3, 6, 16, 2
+    caches = _random_caches(
+        M.cache_specs(cfg, batch=B, cache_len=cache_len), 4)
+    prompt = jax.random.randint(jax.random.PRNGKey(5), (1, S), 0, cfg.vocab)
+    logits, new = M.slot_prefill(p, cfg, prompt, caches, slot,
+                                 cache_len=cache_len)
+    want_logits, fresh, _ = M.prefill(p, cfg, prompt, cache_len=cache_len)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    for old, got, row in zip(jax.tree.leaves(caches), jax.tree.leaves(new),
+                             jax.tree.leaves(fresh)):
+        want = np.asarray(old).copy()
+        want[:, slot] = np.asarray(row)[:, 0]
+        np.testing.assert_array_equal(np.asarray(got), want)

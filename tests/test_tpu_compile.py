@@ -142,12 +142,10 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         assert any(role in n for n in names), (role, sorted(names))
 
 
-def test_internlm2_decode_step_fits_v5e(one_chip):
-    """The engine's decode step at published widths (24 layers, d=2048,
-    16/8 heads, vocab padded to 94208), 8 slots x 4096 cache, compiles
-    for one chip and fits its HBM."""
+def _internlm2_on_chip(one_chip):
+    """internlm2_1_8b's config, its parameters' shapes on the described
+    chip, and the function that puts a shape there."""
     from repro.configs import load_config
-    from repro.launch import engine
     from repro.models import model as M
 
     cfg = load_config("internlm2_1_8b")
@@ -155,6 +153,17 @@ def test_internlm2_decode_step_fits_v5e(one_chip):
                                              sharding=one_chip)
     params = jax.tree.map(on_chip, jax.eval_shape(
         lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)))
+    return cfg, params, on_chip
+
+
+def test_internlm2_decode_step_fits_v5e(one_chip):
+    """The engine's decode step at published widths (24 layers, d=2048,
+    16/8 heads, vocab padded to 94208), 8 slots x 4096 cache, compiles
+    for one chip and fits its HBM."""
+    from repro.launch import engine
+    from repro.models import model as M
+
+    cfg, params, on_chip = _internlm2_on_chip(one_chip)
     caches = jax.tree.map(on_chip, M.cache_specs(cfg, batch=8,
                                                  cache_len=4096))
     compiled = engine._decode_jit.lower(
@@ -165,3 +174,76 @@ def test_internlm2_decode_step_fits_v5e(one_chip):
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2 ** 30:.2f} GiB"
+
+
+#: case -> (slots, cache_len, page_size or None for the contiguous cache):
+#: the serving cells' shapes, and the decode cell's bytes as a page pool
+IN_PLACE = {
+    "decode_backlog": (32, 1024, None),
+    "prefill_backlog": (16, 2112, None),
+    "paged": (32, 1024, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(IN_PLACE))
+def test_decode_step_updates_cache_in_place(one_chip, case):
+    """The engine's decode step writes the donated cache where it lies: no
+    ``copy`` in the compiled program has a cache leaf's shape, and its
+    scratch memory is smaller than one leaf (a scan that rebuilt the cache
+    as its outputs would hold a second whole cache and copy it back)."""
+    from repro.launch import engine
+    from repro.models import model as M
+
+    slots, cache_len, page_size = IN_PLACE[case]
+    cfg, params, on_chip = _internlm2_on_chip(one_chip)
+    tok, pos = _i32((slots, 1), one_chip), _i32((slots,), one_chip)
+    with C.tuning_scope(interpret=False):
+        if page_size is None:
+            caches = jax.tree.map(on_chip, M.cache_specs(
+                cfg, batch=slots, cache_len=cache_len))
+            lowered = engine._decode_jit.lower(params, tok, caches, pos,
+                                               cfg=cfg)
+        else:
+            table_len = cache_len // page_size
+            caches = jax.tree.map(on_chip, M.paged_cache_specs(
+                cfg, num_pages=slots * table_len, page_size=page_size))
+            lowered = engine._decode_paged_jit.lower(
+                params, tok, caches, pos,
+                _i32((slots, table_len), one_chip), cfg=cfg,
+                page_size=page_size)
+        compiled = lowered.compile()
+    _assert_in_place(compiled, caches)
+
+
+def test_slot_prefill_updates_cache_in_place(one_chip):
+    """The engine's prefill at the prefill cell's shape (16 slots x 2,112,
+    a 2,048-token prompt) writes the slot's row into the donated cache:
+    no whole-cache copy, and no batch-1 cache beside it."""
+    from repro.launch import engine
+    from repro.models import model as M
+
+    cfg, params, on_chip = _internlm2_on_chip(one_chip)
+    caches = jax.tree.map(on_chip, M.cache_specs(cfg, batch=16,
+                                                 cache_len=2112))
+    with C.tuning_scope(interpret=False):
+        compiled = engine._prefill_jit.lower(
+            params, _i32((1, 2048), one_chip), caches, _i32((), one_chip),
+            cfg=cfg, cache_len=2112).compile()
+    _assert_in_place(compiled, caches, scratch_leaves=1 / 16)
+
+
+def _assert_in_place(compiled, caches, scratch_leaves=1.0):
+    """No ``copy`` in ``compiled`` has a cache leaf's shape, and its
+    scratch memory is below ``scratch_leaves`` of one leaf's bytes."""
+    text = compiled.as_text()
+    leaves = jax.tree.leaves(caches)
+    for leaf in leaves:
+        shape = "bf16[" + ",".join(map(str, leaf.shape)) + "]"
+        copies = re.findall(
+            r"= " + re.escape(shape) + r"\{[^}]*\} copy\(", text)
+        assert not copies, (shape, len(copies))
+    leaf_bytes = leaves[0].size * leaves[0].dtype.itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < scratch_leaves * leaf_bytes, (temp / 2 ** 30,
+                                                leaf_bytes / 2 ** 30)
+
