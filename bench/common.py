@@ -6,6 +6,8 @@ files and refuse a bad checkout before the accelerator is touched.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
 import pathlib
@@ -52,6 +54,27 @@ def traffic_file(name: str) -> dict:
     if not path.is_file():
         raise SetupError(f"no traffic mix {path}")
     return load_json(path)
+
+
+@functools.lru_cache(maxsize=None)
+def model_module(arch: str):
+    """The family module ``bench/models/<arch>.py`` of the model whose
+    configuration file names ``arch`` first in ``architectures``: its
+    sizes, program config, weight shapes, reference and work (the names
+    ``bench/models/InternLM2ForCausalLM.py`` lists). Loaded once."""
+    path = BENCH / "models" / f"{arch}.py"
+    if not path.is_file():
+        raise SetupError(f"no model module bench/models/{arch}.py for "
+                         f"architecture {arch!r}")
+    return load_file(path, f"bench_model_{arch}")
+
+
+def load_file(path, name: str):
+    """The module in file ``path``, imported under ``name``."""
+    spec_ = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec_)
+    spec_.loader.exec_module(mod)
+    return mod
 
 
 def metrics_for(spec_: dict, cell_name: str, trace: bool) -> list[dict]:

@@ -69,7 +69,7 @@ def _sort(run, seeds, control_seeds) -> list:
             worst = {}
             for k in keys:
                 parts, overflow = sort.output_parts(fn(k, pay), len(devs))
-                got = sort.check_output(k, parts, overflow, device=devs[0])
+                got = sort.check_output(k, parts, overflow, devices=devs)
                 worst = {n: max(worst.get(n, 0), v) for n, v in got.items()}
             row["program"] = worst
         if seed in control_seeds:
@@ -82,7 +82,7 @@ def _sort(run, seeds, control_seeds) -> list:
                 per = ck.shape[0] // len(devs)
                 parts = [(ck[r * per:(r + 1) * per], cv[r * per:(r + 1) * per],
                           per) for r in range(len(devs))]
-                got = sort.check_output(k, parts, 0, device=devs[0])
+                got = sort.check_output(k, parts, 0, devices=devs)
                 worst = {n: max(worst.get(n, 0), v) for n, v in got.items()}
             row["control"] = worst
         rows.append(row)

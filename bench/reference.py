@@ -1,9 +1,9 @@
 """Plain references the benchmark compares the timed path against.
 
-* ``logits``: a dense decoder (InternLM2 as run: RMSNorm, half-split RoPE,
-  grouped-query causal attention, SwiGLU, untied head) in float32 at
-  ``highest`` matmul precision, one layer at a time over one whole
-  sequence: no cache, no kernels, no batching. With ``fp8=True`` every
+* ``logits``: the family module's forward pass (``bench/models/<arch>.py``)
+  in float32 at ``highest`` matmul precision, one layer at a time over one
+  whole sequence: no cache, no kernels, no batching. Its layers are built
+  from ``linear``, ``rmsnorm`` and ``rope``. With ``fp8=True`` every
   linear layer's operands are rounded to float8 e4m3 (per-tensor scale for
   weights, per-row for activations): the control, one precision below the
   bfloat16 the model is served in.
@@ -22,6 +22,8 @@ import functools
 
 import numpy as np
 
+from bench import common
+
 F8_MAX = 448.0  # largest finite float8_e4m3fn
 
 
@@ -33,7 +35,7 @@ def _q8(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _linear(x, w, fp8):
+def linear(x, w, fp8):
     """x (S, i) f32 @ w (i, o) at the highest precision; with ``fp8``
     both operands are rounded to e4m3 first."""
     import jax
@@ -45,13 +47,13 @@ def _linear(x, w, fp8):
     return jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     import jax
 
     return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x (S, heads, hd), half-split rotation at positions 0..S-1."""
     import jax.numpy as jnp
 
@@ -67,39 +69,9 @@ def _rope(x, theta):
 @functools.lru_cache(maxsize=None)
 def _forward(dims: tuple, fp8: bool):
     import jax
-    import jax.numpy as jnp
 
     m = dict(dims)
-    H, KV, hd, eps = m["heads"], m["kv_heads"], m["head_dim"], m["norm_eps"]
-    G = H // KV
-
-    def layer(x, p):
-        S = x.shape[0]
-        h = _rmsnorm(x, p["ln1"]["scale"], eps)
-        q = _linear(h, p["attn"]["wq"], fp8).reshape(S, H, hd)
-        k = _linear(h, p["attn"]["wk"], fp8).reshape(S, KV, hd)
-        v = _linear(h, p["attn"]["wv"], fp8).reshape(S, KV, hd)
-        q, k = _rope(q, m["rope_theta"]), _rope(k, m["rope_theta"])
-        k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
-        s = jnp.einsum("qhd,khd->hqk", q, k,
-                       precision=jax.lax.Precision.HIGHEST) / np.sqrt(hd)
-        causal = jnp.tril(jnp.ones((S, S), bool))
-        a = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", a, v,
-                       precision=jax.lax.Precision.HIGHEST)
-        x = x + _linear(o.reshape(S, H * hd), p["attn"]["wo"], fp8)
-        h = _rmsnorm(x, p["ln2"]["scale"], eps)
-        gate = jax.nn.silu(_linear(h, p["mlp"]["w_gate"], fp8))
-        up = _linear(h, p["mlp"]["w_up"], fp8)
-        return x + _linear(gate * up, p["mlp"]["w_down"], fp8), None
-
-    def forward(params, tokens):
-        x = params["embed"]["embed"][tokens].astype(jnp.float32)
-        x, _ = jax.lax.scan(layer, x, params["layers"])
-        x = _rmsnorm(x, params["final_norm"]["scale"], eps)
-        return _linear(x, params["head"]["unembed"], fp8)
-
-    return jax.jit(forward)
+    return jax.jit(common.model_module(m["arch"]).forward(m, fp8))
 
 
 def logits(dims: dict, params, tokens, *, fp8: bool = False):

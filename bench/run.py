@@ -6,9 +6,10 @@
 The cell's configuration, traffic mix and limits are found by the names
 in ``BENCHMARK.json``: ``bench/configs/<config>.json``,
 ``bench/workloads/<traffic>.json`` and ``bench/limits/<cell>.json``; each
-per-layer metric is read by ``bench/metrics/<metric>.py``. The traffic
-file's ``kind`` picks the runner: ``serve_backlog`` (``bench/serve.py``)
-or ``sort`` (``bench/sort.py``).
+per-layer metric is read by ``bench/metrics/<metric>.py``, and a model
+configuration's family by ``bench/models/<architectures[0]>.py``. The
+traffic file's ``kind`` picks the runner: ``serve_backlog``
+(``bench/serve.py``) or ``sort`` (``bench/sort.py``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (with ``--trace 0`` the cell's end-to-end
@@ -21,7 +22,6 @@ finds no TPU, fewer chips than the cell asks for, or no program to run
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import os
 import pathlib
 import shutil
@@ -38,11 +38,7 @@ def reader(name: str):
     path = BENCH / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SetupError(f"no reader {path} for per-layer metric {name!r}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return common.load_file(path, f"bench_metric_{name}").read
 
 
 class Tracer:
@@ -177,6 +173,9 @@ def prepare(argv=None, *, require_tpu: bool = True):
     if not limits_path.is_file():
         raise SetupError(f"no limits {limits_path}")
     limits = common.load_json(limits_path)
+    if config["kind"] == "model":
+        # a family without a module stops here, before the device is touched
+        common.model_module(config["architectures"][0])
     listed = common.metrics_for(spec, cell["name"], bool(args.trace))
     readers = ({m["name"]: reader(m["name"]) for m in listed} if args.trace
                else {m["name"]: None for m in listed})

@@ -27,36 +27,16 @@ from bench import common, reference, traffic as T, weights
 
 def model_dims(cfg_file: dict) -> dict:
     """The sizes the benchmark's weights, reference and work functions
-    read, from a configuration file of published keys."""
-    d, H = cfg_file["hidden_size"], cfg_file["num_attention_heads"]
-    return {
-        "layers": cfg_file["num_hidden_layers"],
-        "d_model": d,
-        "heads": H,
-        "kv_heads": cfg_file["num_key_value_heads"],
-        "head_dim": cfg_file.get("head_dim", d // H),
-        "d_ff": cfg_file["intermediate_size"],
-        "vocab": cfg_file["vocab_size"],
-        "padded_vocab": cfg_file["padded_vocab"],
-        "rope_theta": float(cfg_file["rope_theta"]),
-        "norm_eps": float(cfg_file["rms_norm_eps"]),
-        "dtype": cfg_file["torch_dtype"],
-    }
+    read, from a configuration file of published keys: the family
+    module's, with ``arch``, the name that finds that module again."""
+    arch = cfg_file["architectures"][0]
+    return dict(common.model_module(arch).dims(cfg_file), arch=arch)
 
 
 def program_config(cfg_file: dict):
     """The program's ModelConfig for the same model."""
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-
     m = model_dims(cfg_file)
-    cfg = ModelConfig(
-        name=cfg_file["name"], family="dense", n_layers=m["layers"],
-        d_model=m["d_model"], n_heads=m["heads"], n_kv_heads=m["kv_heads"],
-        d_ff=m["d_ff"], vocab=m["vocab"], head_dim=m["head_dim"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
-        dtype=jnp.dtype(m["dtype"]),
-    )
+    cfg = common.model_module(m["arch"]).program_config(cfg_file, m)
     if cfg.padded_vocab() != m["padded_vocab"]:
         raise common.SetupError(
             f"the program pads the vocabulary to {cfg.padded_vocab()}, the "

@@ -73,13 +73,19 @@ def sort_window(run, *, program_hook=None):
     import jax
 
     cfg, tr, devs = run.config, run.traffic, run.devices
+    t_dev = time.perf_counter() - common.T_START
     fn, mesh = _program(cfg, tr, devs)
     if program_hook is not None:
         fn = program_hook(fn, mesh)
     keys, pay = inputs(cfg, tr, run.seed, devs, mesh)
-    # warm-up: compile, then one sort of every input
+    jax.block_until_ready((keys, pay))
+    t_in = time.perf_counter() - common.T_START
+    # warm-up: compile (or load from the cache), then one sort of every
+    # input
     jax.block_until_ready([fn(k, pay) for k in keys])
     setup_s = time.perf_counter() - common.T_START
+    common.log(f"set-up: devices at {t_dev:.1f} s, inputs made at "
+               f"{t_in:.1f} s, warm-up done at {setup_s:.1f} s")
 
     outs = [None] * len(keys)
     count, queued, done_t = 0, collections.deque(), []
@@ -121,8 +127,7 @@ def _part_checks():
 
     def part(ref_k, keys, vals, pay, off, cnt):
         """One rank's output (padded, ``cnt`` valid) against the sorted
-        reference from ``off`` on: wrong keys, broken pairs, and a tally
-        of every payload seen."""
+        reference from ``off`` on: wrong keys and broken pairs."""
         width = vals.shape[0]
         idx = jnp.arange(width)
         valid = idx < cnt
@@ -141,31 +146,40 @@ def _part_checks():
     return jax.jit(part), jax.jit(tally, donate_argnums=0)
 
 
-def check_output(keys, parts, overflow: int, *, device) -> dict:
+def check_output(keys, parts, overflow: int, *, devices) -> dict:
     """Counts of what is wrong in one sort's output. ``parts``: per rank,
-    in rank order, (keys, payload, valid count) on any device."""
+    in rank order, (keys, payload, valid count) on any device. Rank r is
+    checked on ``devices[r]`` (on the last one where there are fewer),
+    all ranks at once, each given the whole input and the sorted
+    reference; every payload is tallied on ``devices[0]``."""
     import jax
     import jax.numpy as jnp
 
     part, tally = _part_checks()
-    with jax.default_device(device):
-        k = jax.device_put(keys, device)
-        ref_k = reference.sorted_keys(k)
-        seen = jnp.zeros(k.shape, jnp.int32)
-        keys_wrong = pairs_wrong = 0
-        off = 0
-        for vals, pay, cnt in parts:
-            vals = jax.device_put(vals, device)
-            pay = jax.device_put(pay, device)
-            kw, pw = part(ref_k, k, vals, pay, jnp.int32(off),
-                          jnp.int32(cnt))
-            keys_wrong += int(kw)
-            pairs_wrong += int(pw)
-            seen = tally(seen, pay, jnp.int32(cnt))
-            off += int(cnt)
+    home = devices[0]
+    whole = {home: jax.device_put(keys, home)}
+    ref_k = reference.sorted_keys(whole[home])
+    offs = np.cumsum([0] + [int(c) for _, _, c in parts]).tolist()
+    with jax.default_device(home):
+        seen = jnp.zeros(ref_k.shape, jnp.int32)
+    counts = []
+    for r, (vals, pay, cnt) in enumerate(parts):
+        dev = devices[min(r, len(devices) - 1)]
+        if dev not in whole:
+            whole[dev] = jax.device_put(keys, dev)
+        with jax.default_device(dev):
+            counts.append(part(jax.device_put(ref_k, dev), whole[dev],
+                               jax.device_put(vals, dev),
+                               jax.device_put(pay, dev),
+                               jnp.int32(offs[r]), jnp.int32(cnt)))
+        with jax.default_device(home):
+            seen = tally(seen, jax.device_put(pay, home), jnp.int32(cnt))
+    with jax.default_device(home):
         missing = int(jnp.sum(seen != 1))
-    return {"keys_wrong": keys_wrong + abs(off - int(k.shape[0])),
-            "pairs_wrong": pairs_wrong + missing, "overflow": overflow}
+    return {"keys_wrong": sum(int(kw) for kw, _ in counts)
+            + abs(offs[-1] - int(ref_k.shape[0])),
+            "pairs_wrong": sum(int(pw) for _, pw in counts) + missing,
+            "overflow": overflow}
 
 
 def output_parts(out, ranks: int):
@@ -192,7 +206,7 @@ def readings(w: dict, devs) -> dict:
             continue
         parts, overflow = output_parts(out, len(devs))
         del out
-        got = check_output(k, parts, overflow, device=devs[0])
+        got = check_output(k, parts, overflow, devices=devs)
         worst = {n: max(worst[n], got[n]) for n in worst}
     return worst
 

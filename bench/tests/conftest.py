@@ -18,10 +18,8 @@ for p in (str(ROOT), str(ROOT / "src")):
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 #: Tiny stand-ins for the cells' sizes: the same runners, configuration
-#: keys and checks, at a size the CPU runs in seconds.
-TINY_MODEL = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-                  num_key_value_heads=2, intermediate_size=128,
-                  vocab_size=250, padded_vocab=2048)
+#: keys and checks, at a size the CPU runs in seconds (a model's own from
+#: its family module's ``TINY``).
 TINY_SERVE = dict(slots=4, cache_len=64, prompt_pad=32, warmup_max_new=4,
                   check_requests=3, trace_seconds=1,
                   prompt_len=dict(dist="lognormal", median=12, sigma=0.5,
@@ -54,7 +52,7 @@ def tiny_run(cell_name: str, *, seed: int = 2 ** 33 + 5, seconds: float = 2,
     traffic = dict(common.traffic_file(cell["traffic"]))
     limits = common.load_json(common.BENCH / "limits" / f"{cell_name}.json")
     if traffic["kind"] == "serve_backlog":
-        config.update(TINY_MODEL)
+        config.update(common.model_module(config["architectures"][0]).TINY)
         traffic.update(TINY_SERVE)
     else:
         config["n_per_chip"] = 4096

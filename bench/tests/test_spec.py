@@ -135,3 +135,99 @@ def test_new_cell_and_metric_need_only_new_files(tmp_path, with_reader):
     assert proc.returncode != 0 and _no_result(proc)
     want = "no TPU" if with_reader else "no reader"
     assert want in proc.stderr, proc.stderr[-2000:]
+
+
+#: The functions every family module (``bench/models/<arch>.py``) gives,
+#: beside ``TINY``.
+FAMILY_API = ("dims", "program_config", "weight_shapes", "forward",
+              "flops_per_token", "attention_flops")
+MODEL_FILES = [c["file"] for c in SPEC["configs"]
+               if common.load_json(common.ROOT / c["file"])["kind"] == "model"]
+
+
+@pytest.mark.parametrize("file", MODEL_FILES)
+def test_every_model_config_has_a_module(file):
+    cfg = common.load_json(common.ROOT / file)
+    mod = common.model_module(cfg["architectures"][0])
+    assert all(callable(getattr(mod, n)) for n in FAMILY_API)
+    assert set(mod.TINY) <= set(cfg)
+
+
+NEW_FAMILY = "TwinLMForCausalLM"
+NEW_CELL = "twin_lm.decode_backlog"
+
+
+def _new_family(tmp_path, with_module: bool):
+    """A checkout with a configuration of a new family: its module (a copy
+    of InternLM2's under another architecture name), its config file, a
+    cell and its limits, and the cell appended to every serving metric's
+    ``workloads``; new files and entries only."""
+    root = _checkout(tmp_path)
+    os.symlink(common.ROOT / "src", root / "src")
+    bench = root / "bench"
+    if with_module:
+        shutil.copy(bench / "models" / "InternLM2ForCausalLM.py",
+                    bench / "models" / f"{NEW_FAMILY}.py")
+    cfg = json.loads((bench / "configs" / "internlm2_1_8b.json").read_text())
+    cfg.update(name="twin_lm", architectures=[NEW_FAMILY])
+    (bench / "configs" / "twin_lm.json").write_text(json.dumps(cfg))
+    (bench / "limits" / f"{NEW_CELL}.json").write_text(
+        (bench / "limits" / "internlm2_1_8b.decode_backlog.json")
+        .read_text())
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({
+        "name": "twin_lm", "source": "test", "reduced": [], "why": "test",
+        "file": "bench/configs/twin_lm.json"})
+    spec["workloads"].append({
+        "name": NEW_CELL, "config": "twin_lm", "traffic": "decode_backlog",
+        "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "internlm2_1_8b.decode_backlog" in m.get("workloads", []):
+            m["workloads"].append(NEW_CELL)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root
+
+
+@pytest.mark.parametrize("with_module", [True, False])
+def test_new_family_needs_only_new_files(tmp_path, with_module):
+    """With its module the new family's cell gets as far as the look for
+    a chip; without it the run stops at the missing module, by name."""
+    root = _new_family(tmp_path, with_module)
+    proc = _bench_run(root, "--workload", NEW_CELL, "--seed",
+                      str(2 ** 33 + 9), "--seconds", "1", "--trace", "0")
+    assert proc.returncode != 0 and _no_result(proc)
+    want = ("no TPU" if with_module
+            else f"no model module bench/models/{NEW_FAMILY}.py")
+    assert want in proc.stderr, proc.stderr[-2000:]
+
+
+TWIN = r"""
+import json, sys
+sys.path[:0] = [sys.argv[1], sys.argv[1] + "/src"]
+import jax
+jax.config.update("jax_enable_compilation_cache", False)
+from bench import common, serve
+from bench.tests.conftest import tiny_run
+run = tiny_run(sys.argv[2])
+serve.run(run)
+print(json.dumps({"module": common.model_module(sys.argv[3]).__file__,
+                  "correct": run.result["correct"],
+                  "failed": run.result["failed"],
+                  "metrics": sorted(run.result["metrics"])}))
+"""
+
+
+def test_new_family_serves_a_backlog(tmp_path):
+    """A tiny run of the new family's cell, through its own module, serves
+    its backlog and reads correct."""
+    root = _new_family(tmp_path, True)
+    proc = subprocess.run(
+        [sys.executable, "-c", TWIN, str(root), NEW_CELL, NEW_FAMILY],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert got["module"] == str(root / "bench" / "models"
+                                / f"{NEW_FAMILY}.py")
+    assert got["correct"] and got["failed"] == 0
+    assert got["metrics"] == ["serve_tok_s", "setup_s", "tpot_p95_ms"]

@@ -14,6 +14,10 @@ import json
 import pathlib
 import re
 
+#: How far the devices' clock reads behind the host's: 0.2-1.3 ms on a
+#: TPU v5e (``bench/spans.py`` measures it per profile), taken at its most.
+SKEW_S = 1.3e-3
+
 NAMES = json.loads((pathlib.Path(__file__).parent / "names.json").read_text())
 
 
@@ -102,8 +106,28 @@ class Trace:
     def devices(self) -> list:
         return sorted(self.ops)
 
+    def window(self):
+        """(start, end) of the traced slice on the devices' clock: from
+        the host's first event to its last, moved back by ``SKEW_S``;
+        None where the trace holds no host event."""
+        if not self.host:
+            return None
+        return (min(s for s, _, _ in self.host) - SKEW_S,
+                max(e for _, e, _ in self.host) - SKEW_S)
+
     def busy_s(self, dev: int) -> float:
-        return _length(_union((s, e) for s, e, _ in self.ops.get(dev, [])))
+        """Device time inside the traced slice in which some operation
+        ran. The devices start to record before the host does, so a
+        program in flight when the profile started leaves operations
+        from before the slice (on a v5e 2x2, up to 29 ms): they are cut
+        at its start, or busy time would read above its length."""
+        ops = [(s, e) for s, e, _ in self.ops.get(dev, [])]
+        win = self.window()
+        if win is not None:
+            lo, hi = win
+            ops = [(max(s, lo), min(e, hi)) for s, e in ops
+                   if e > lo and s < hi]
+        return _length(_union(ops))
 
     def mean_busy_s(self) -> float:
         devs = self.devices
@@ -114,11 +138,31 @@ class Trace:
         return [(s, e) for s, e, n in self.modules.get(dev, [])
                 if rx.search(n)]
 
+    def whole_modules(self, pattern: str, dev: int) -> list:
+        """(start, end) of the executions of the matching programs that
+        lie wholly inside the slice: a program that ran when the profile
+        started or stopped holds the first or the last operation on
+        ``dev``, and is left out."""
+        ops = self.ops.get(dev, [])
+        if not ops:
+            return []
+        lo = min(s for s, _, _ in ops)
+        hi = max(e for _, e, _ in ops)
+        return [(s, e) for s, e in self.modules_matching(pattern, dev)
+                if lo < s and e < hi]
+
     def op_time_in(self, pattern: str, dev: int, module: str) -> float:
         """Device time of the operations whose name matches ``pattern``
         and whose midpoint lies inside an execution of a program whose
         name matches ``module`` (overlaps counted once)."""
-        spans = _union(self.modules_matching(module, dev))
+        return self.op_time_within(pattern, dev,
+                                   self.modules_matching(module, dev))
+
+    def op_time_within(self, pattern: str, dev: int, spans) -> float:
+        """Device time of the operations whose name matches ``pattern``
+        and whose midpoint lies inside one of ``spans`` (overlaps counted
+        once)."""
+        spans = _union(spans)
         rx = re.compile(pattern)
         return _length(_union(
             (s, e) for s, e, n in self.ops.get(dev, [])

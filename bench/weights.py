@@ -1,40 +1,22 @@
-"""Seeded weights of a dense decoder, made on the device in one program.
+"""Seeded weights of a served model, made on the device in one program.
 
-The tree has the layout the serving engine reads (stacked layers under
-``layers``; ``embed``/``final_norm``/``head`` at the top). The benchmark,
-not the program, makes the weights, so the plain reference can make the
-same ones again from the same seed without taking anything from the
-program.
+The tree is the family module's (``weight_shapes`` in
+``bench/models/<arch>.py``), nested at its ``/``s into the layout the
+serving engine reads. The benchmark, not the program, makes the weights,
+so the plain reference can make the same ones again from the same seed
+without taking anything from the program.
 
 Matrices are N(0, 1/fan_in), the embedding N(0, 1) and norm scales 1, so
 the logits have a standard deviation near 1: the top-50 / top-0.9 cut of
 the sampler then keeps a few tens of tokens, as it does for a trained
-model's flatter steps.
+model's flatter steps. Leaves are drawn in the sorted order of their
+paths, one key each from the seed.
 """
 from __future__ import annotations
 
 import functools
 
-
-def shapes(m: dict) -> dict:
-    """name -> (shape, kind) for the model described by ``m`` (the keys of
-    ``serve.model_dims``)."""
-    L, d, ff, V = m["layers"], m["d_model"], m["d_ff"], m["padded_vocab"]
-    H, KV, hd = m["heads"], m["kv_heads"], m["head_dim"]
-    return {
-        "embed/embed": ((V, d), "embed"),
-        "final_norm/scale": ((d,), "norm"),
-        "head/unembed": ((d, V), "matrix"),
-        "layers/ln1/scale": ((L, d), "norm"),
-        "layers/ln2/scale": ((L, d), "norm"),
-        "layers/attn/wq": ((L, d, H * hd), "matrix"),
-        "layers/attn/wk": ((L, d, KV * hd), "matrix"),
-        "layers/attn/wv": ((L, d, KV * hd), "matrix"),
-        "layers/attn/wo": ((L, H * hd, d), "matrix"),
-        "layers/mlp/w_gate": ((L, d, ff), "matrix"),
-        "layers/mlp/w_up": ((L, d, ff), "matrix"),
-        "layers/mlp/w_down": ((L, ff, d), "matrix"),
-    }
+from bench import common
 
 
 def _nest(flat: dict) -> dict:
@@ -54,7 +36,7 @@ def _maker(dims: tuple):
     import jax.numpy as jnp
 
     m = dict(dims)
-    table = shapes(m)
+    table = common.model_module(m["arch"]).weight_shapes(m)
     dtype = jnp.dtype(m["dtype"])
 
     def make(seed):

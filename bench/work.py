@@ -5,30 +5,31 @@ padding, recomputation and masked-out columns are waste, not work.
 """
 from __future__ import annotations
 
+from bench import common
+
 
 def dense_matmul_flops_per_token(m: dict) -> int:
-    """2 x the weights a token passes through: every layer's projections
-    and MLP, and the head over the true vocabulary (the embedding is a
-    lookup)."""
-    d, H, KV, hd, ff = (m["d_model"], m["heads"], m["kv_heads"],
-                        m["head_dim"], m["d_ff"])
-    per_layer = d * (H + 2 * KV) * hd + H * hd * d + 3 * d * ff
-    return 2 * (m["layers"] * per_layer + d * m["vocab"])
+    """2 x the weights a token passes through, in its linear layers and
+    the head over the true vocabulary: the family module's count (for a
+    model with experts, those a token is routed to). The name predates
+    the family modules; rename it to ``flops_per_token`` together with
+    its calls in ``bench/tests/test_work.py``."""
+    return common.model_module(m["arch"]).flops_per_token(m)
 
 
 def attention_flops(m: dict, context: int) -> int:
-    """Scores and weighted values of one query over ``context`` keys, in
-    every layer: 2 x 2 x heads x head_dim x context."""
-    return m["layers"] * 4 * m["heads"] * m["head_dim"] * context
+    """One query attending over ``context`` keys, in every layer: the
+    family module's count."""
+    return common.model_module(m["arch"]).attention_flops(m, context)
 
 
 def prefill_flops(m: dict, length: int) -> int:
-    """One causal pass over a prompt of its true ``length``; the head
-    only at the last position, whose logits are sampled."""
+    """One causal pass over a prompt of its true ``length``, position i
+    attending over i + 1 keys; the head only at the last position, whose
+    logits are sampled."""
     head = 2 * m["d_model"] * m["vocab"]
     body = dense_matmul_flops_per_token(m) - head
-    attn = m["layers"] * 4 * m["heads"] * m["head_dim"] \
-        * length * (length + 1) // 2
+    attn = common.model_module(m["arch"]).prefill_attention_flops(m, length)
     return length * body + attn + head
 
 
